@@ -16,6 +16,7 @@ from jax.experimental import pallas as pl
 from repro.core.platform import Platform, XHeepConfig
 from repro.core.power import PowerDomain, PowerState
 from repro.core.xaif import AcceleratorSpec, PortSpec
+from repro.kernels import backend
 from repro.sharding.params import Axes
 
 
@@ -30,7 +31,7 @@ def _mix_kernel(x_ref, o_ref):
     o_ref[0] = x
 
 
-def mix(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def mix(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     b, n = x.shape
     return pl.pallas_call(
         _mix_kernel,
@@ -38,7 +39,7 @@ def mix(x: jax.Array, *, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((1, n), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.uint32),
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(x)
 
 

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 NEG_INF = -1e30
 
 
@@ -69,7 +71,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_hm(q, k, v, *, groups: int, causal: bool = True,
                        window: int | None = None, sq: int | None = None,
                        sk: int | None = None, q_block: int = 128,
-                       kv_block: int = 128, interpret: bool = True):
+                       kv_block: int = 128, interpret: bool | None = None):
     """Head-major flash attention (see module docstring for layout)."""
     bh, sq_pad, d = q.shape
     bkv, sk_pad, _ = k.shape
@@ -103,5 +105,5 @@ def flash_attention_hm(q, k, v, *, groups: int, causal: bool = True,
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(q, k, v)

@@ -34,7 +34,7 @@ def _pad_to(x, axis, mult):
                                              "q_block", "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     q_offset: int = 0, kv_len=None, q_block: int = 128,
-                    kv_block: int = 128, interpret: bool = True):
+                    kv_block: int = 128, interpret: bool | None = None):
     """Batch-seq-major entry: q (B,S,H,D); k/v (B,S,K,D). GQA handled by the
     kernel's block index mapping (no KV materialization)."""
     if kv_len is not None:

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import backend
+
 
 def _conv_kernel(x_ref, xprev_ref, w_ref, o_ref, *, width: int, s_block: int):
     si = pl.program_id(1)
@@ -34,7 +36,7 @@ def _conv_kernel(x_ref, xprev_ref, w_ref, o_ref, *, width: int, s_block: int):
 
 
 def conv1d_causal(x, w, *, s_block: int = 256, d_block: int = 128,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """x: (B, S, D), w: (W, D) -> (B, S, D)."""
     b, s, d = x.shape
     width = w.shape[0]
@@ -58,5 +60,5 @@ def conv1d_causal(x, w, *, s_block: int = 256, d_block: int = 128,
         out_specs=pl.BlockSpec((1, s_block, d_block),
                                lambda i, si, di: (i, si, di)),
         out_shape=jax.ShapeDtypeStruct((b, s, d), x.dtype),
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(x, x, w)

@@ -29,7 +29,7 @@ def _pick_block(n, pref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def conv1d(x, w, *, interpret: bool = True):
+def conv1d(x, w, *, interpret: bool | None = None):
     """x: (B,S,D), w: (W,D) -> (B,S,D) causal depthwise conv."""
     b, s, d = x.shape
     sb = _pick_block(s, 256)

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
     di = pl.program_id(3)
@@ -36,7 +38,7 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_scr):
 
 
 def grouped_matmul(x, w, *, c_block: int = 128, f_block: int = 128,
-                   d_block: int = 256, interpret: bool = True):
+                   d_block: int = 256, interpret: bool | None = None):
     """x: (E, C, D), w: (E, D, F) -> (E, C, F)."""
     e, c, d = x.shape
     f = w.shape[-1]
@@ -57,5 +59,5 @@ def grouped_matmul(x, w, *, c_block: int = 128, f_block: int = 128,
                                lambda ei, ci, fi, di: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((c_block, f_block), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(x, w)

@@ -25,7 +25,7 @@ def _blocks_for(c, d, f):
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "interpret"))
-def moe_ffn(xg, p, kind: str = "swiglu", *, interpret: bool = True):
+def moe_ffn(xg, p, kind: str = "swiglu", *, interpret: bool | None = None):
     """xg: (E, C, D); p: expert weights {w_gate, w_up, w_down} (E,...)."""
     e, c, d = xg.shape
     f = p["w_gate"].shape[-1]

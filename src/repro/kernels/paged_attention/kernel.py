@@ -43,6 +43,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 NEG_INF = -1e30
 
 
@@ -105,7 +107,7 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_attention_kernel(q, k_pool, v_pool, tables, lengths, *,
                            window: int | None = None,
                            scale: float | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """Fused paged single-query attention (see module docstring for layout).
 
     Returns o (B, H, D). ``scale`` defaults to ``1/sqrt(D)`` — pass the
@@ -144,5 +146,5 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)

@@ -7,14 +7,14 @@ pool* through the block table. Two backends:
 
 * ``impl="ref"`` — the pure-jax oracle (``ref.py``). Its gather + masked
   attention is arranged to be bit-identical to the PR 2 lane-cache decode,
-  so it is also the engine's default: paged serving changes memory layout,
-  never tokens.
+  so it is what the engine runs off the TPU: paged serving changes memory
+  layout, never tokens.
 * ``impl="pallas"`` — the fused TPU kernel (``kernel.py``): block-table
   scalar prefetch, one pool page streamed per grid step, online softmax in
-  VMEM scratch. On a real TPU the append scatter fuses into the same
-  program via ``input_output_aliases``; in this CPU repro the scatter is an
-  XLA in-place update on the donated pool and the kernel runs in interpret
-  mode.
+  VMEM scratch. The engine runs it whenever JAX runs on a TPU, compiled
+  (``repro.kernels.backend`` decides interpret mode from the backend). The
+  append scatter is an XLA in-place update on the donated pool under both
+  backends.
 
 The XAIF contract mirrors the paper's CGRA plug-in: master read ports for
 the query and the two pool planes plus the block table, one master write
@@ -33,7 +33,7 @@ from repro.sharding.params import Axes
 
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
                     window: int | None = None, impl: str = "ref",
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Single-query paged attention over a (P, ps, K, D) page pool.
 
     q (B, H, D); tables (B, NP) int32 page ids; lengths (B,) valid counts.
@@ -52,7 +52,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 
 def paged_decode_append(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
                         append_mask=None, window: int | None = None,
-                        impl: str = "ref", interpret: bool = True):
+                        impl: str = "ref", interpret: bool | None = None):
     """Fused decode step: append the new KV entry, attend over it in place.
 
     Appends ``k_new[b]``/``v_new[b]`` at position ``lengths[b]`` of slot

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 
 def _rglru_kernel(a_ref, b_ref, y_ref, h_out_ref, h_scr, *, s_block: int):
     si = pl.program_id(2)
@@ -44,7 +46,7 @@ def _rglru_kernel(a_ref, b_ref, y_ref, h_out_ref, h_scr, *, s_block: int):
 
 
 def rglru_scan(a, b, *, s_block: int = 128, w_block: int = 128,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """a, b: (B, S, W). Returns (y (B,S,W) fp32-accurate, h_final (B,W))."""
     bsz, s, w = a.shape
     s_block = min(s_block, s)
@@ -69,5 +71,5 @@ def rglru_scan(a, b, *, s_block: int = 128, w_block: int = 128,
             jax.ShapeDtypeStruct((bsz, w), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, w_block), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(a, b)

@@ -15,7 +15,7 @@ from repro.sharding.params import Axes
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rglru(a, b, h0=None, *, interpret: bool = True):
+def rglru(a, b, h0=None, *, interpret: bool | None = None):
     """a, b: (B,S,W) -> (ys (B,S,W), h_final (B,W))."""
     if h0 is not None:
         b = b.at[:, 0].add(a[:, 0] * h0.astype(b.dtype))

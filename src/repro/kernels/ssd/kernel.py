@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 
 def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, y_ref, st_out_ref, state_scr, *,
                 chunk: int):
@@ -64,7 +66,7 @@ def _ssd_kernel(x_ref, da_ref, b_ref, c_ref, y_ref, st_out_ref, state_scr, *,
         st_out_ref[0] = state_scr[...].astype(st_out_ref.dtype)
 
 
-def ssd_hm(x, da, b, c, *, chunk: int, interpret: bool = True):
+def ssd_hm(x, da, b, c, *, chunk: int, interpret: bool | None = None):
     """Head-major SSD scan. Returns (y (BH,S,P), state (BH,P,N))."""
     bh, s, p = x.shape
     n = b.shape[-1]
@@ -90,5 +92,5 @@ def ssd_hm(x, da, b, c, *, chunk: int, interpret: bool = True):
             jax.ShapeDtypeStruct((bh, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=backend.interpret(interpret),
     )(x, da, b, c)

@@ -15,7 +15,7 @@ from repro.sharding.params import Axes
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dA, B, C, *, chunk: int, init_state=None, interpret: bool = True):
+def ssd(x, dA, B, C, *, chunk: int, init_state=None, interpret: bool | None = None):
     """Model-layout entry: x (b,s,h,p), dA (b,s,h), B/C (b,s,h,n) ->
     (y (b,s,h,p), state (b,h,p,n))."""
     if init_state is not None:

@@ -173,8 +173,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
 
     platform = platform or build_platform()
     if "mesh" in var and mesh_name == "single":
-        from repro.core import compat
-        mesh = compat.make_mesh(var["mesh"], ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(var["mesh"], ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=MESHES[mesh_name]["multi_pod"])
     chips = MESHES[mesh_name]["chips"]

@@ -4,34 +4,39 @@ Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required for the dry-run's host-device
 override to land before first jax initialization.
 
-All meshes are built through :mod:`repro.core.compat` so the module works on
-jax versions with and without ``jax.sharding.AxisType``.
+Every mesh is built by :func:`make_mesh` with ``Auto`` axis types: the
+sharding rules place arrays with ``NamedSharding`` and let the compiler
+propagate, which is what ``Auto`` means (``jax.make_mesh`` itself now
+defaults to ``Explicit``).
 """
 
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType
 
-from repro.core import compat
+
+def make_mesh(shape, axes, *, devices=None):
+    """A mesh of ``shape`` named ``axes`` (Auto axis types); ``devices``
+    selects the devices explicitly (default: the first of ``jax.devices()``)."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests / small-scale runs."""
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh with the production axis names, for CPU smoke runs."""
     n = len(jax.devices())
     shape = (1, n) if n == 1 else (n, 1)
-    return compat.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def serve_tp_mesh(tp: int, devices=None):
@@ -45,10 +50,7 @@ def serve_tp_mesh(tp: int, devices=None):
                          f"{len(devices)} (set "
                          "--xla_force_host_platform_device_count or run on "
                          "a larger host)")
-    import numpy as np
-
-    return compat.make_mesh((tp,), ("model",),
-                            devices=np.asarray(devices[:tp]))
+    return make_mesh((tp,), ("model",), devices=np.asarray(devices[:tp]))
 
 
 def replica_meshes(replicas: int, tp: int, devices=None):

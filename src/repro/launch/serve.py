@@ -42,7 +42,7 @@ def main(argv=None):
     sv = build_sharded_serve(cfg, mesh, rules, args.batch, max_len,
                              prefill_len=args.prompt_len)
     key = jax.random.key(args.seed)
-    params = P.cast_tree(P.init_tree(registry.decls(cfg), key), jnp.bfloat16)
+    params = P.init_tree(registry.decls(cfg), key, dtype=jnp.bfloat16)
 
     done = {"flag": False}
 
@@ -81,4 +81,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

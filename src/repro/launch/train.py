@@ -74,8 +74,9 @@ def main(argv=None):
         print(f"resumed from step {start_step}")
     else:
         key = jax.random.key(args.seed)
-        params = P.cast_tree(P.init_tree(decls, key),
-                             jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
+        params = P.init_tree(
+            decls, key,
+            dtype=jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
         from repro.train import optim as optim_lib
 
         opt_state = optim_lib.get(tc.optimizer).init(params)
@@ -108,4 +109,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

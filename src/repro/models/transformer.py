@@ -320,7 +320,10 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
     Every per-slot quantity (rope position, KV length, page chain) is a
     batched vector, so one launch serves ragged slots; the attention itself
     is the fused paged kernel (``repro.kernels.paged_attention``), reading
-    K/V in place from the pool through the block table.
+    K/V in place from the pool through the block table. ``impl`` (None)
+    follows the platform: the compiled Pallas kernel on a TPU, the
+    bit-exact ``ref`` gather elsewhere; pass it only to pit one against
+    the other.
 
     ``tp_axis`` names the mesh axis this step runs tensor-parallel over
     (inside ``shard_map``): params arrive head-sharded (wq/wk/wv slices),
@@ -332,12 +335,13 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
     the gather is replicated compute. ``None`` (default) is the
     single-device path, bit-identical by construction.
     """
+    from repro.kernels import backend
     from repro.kernels.paged_attention import ops as paged_ops
 
     if window is None:
         window = cfg.sliding_window
     if impl is None:
-        impl = "pallas" if cfg.attn_impl == "pallas" else "ref"
+        impl = "pallas" if backend.on_tpu() else "ref"
     positions = lengths[:, None]
     x = _embed(params, cfg, tokens[:, None], None, positions)
     il = cfg.moe_interleave if cfg.moe_experts >= 2 else 1

@@ -100,7 +100,7 @@ from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.runtime.ft import RequestJournal
 from repro.serve.paged import (PagePool, mesh_tp, paged_chunk_fn,
-                               paged_step_fn, place_params)
+                               paged_step_fn)
 from repro.serve.pages import PageTable
 from repro.serve.sampling import (GREEDY, SamplingParams, sample, seed_key,
                                   zero_keys)
@@ -415,16 +415,17 @@ class ContinuousBatchingEngine:
             raise ValueError("prefill_chunk must be >= 1")
         self.cfg = cfg
         # tensor parallelism: a mesh pins this engine's decode to a device
-        # slice — params land head-sharded (wq/wk/wv) via place_params,
-        # the pool arena shards its KV-head axis, and the jitted step runs
-        # under shard_map. All host bookkeeping (slots, block tables,
-        # journal, sampling chains) is mesh-invariant, so TP changes
-        # where bytes live, never which tokens come out.
+        # slice — params land head-sharded (wq/wk/wv) through the pool's
+        # placement cache, the pool arena shards its KV-head axis, and the
+        # jitted step runs under shard_map. All host bookkeeping (slots,
+        # block tables, journal, sampling chains) is mesh-invariant, so TP
+        # changes where bytes live, never which tokens come out.
         self.mesh = mesh
         self.tp_axis = tp_axis
         self.tp = mesh_tp(mesh, tp_axis) if mesh is not None else 1
-        self.params = (place_params(cfg, params, mesh, tp_axis)
-                       if mesh is not None else params)
+        if mesh is not None:
+            R.validate_serve_tp(cfg, self.tp)
+        self.params = params
         self.n_slots = slots
         self.max_len = max_len
         owns_platform = platform is None
@@ -514,6 +515,9 @@ class ContinuousBatchingEngine:
                 # not O(device_len) — the ring bound is the pool budget
                 self._pool = PagePool(slots * self._np_slot + cap, self._ps)
             self._arena = self._pool.arena(cfg, mesh=mesh, tp_axis=tp_axis)
+            if mesh is not None:
+                self.params = self._pool.placed_params(cfg, params, mesh,
+                                                       tp_axis)
         if page_table is not None:
             self.pages: PageTable | None = page_table
         elif page_size:

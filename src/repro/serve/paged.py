@@ -57,7 +57,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core import compat
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.serve.sampling import sample, split_keys
@@ -69,11 +68,6 @@ __all__ = ["PagePool", "PoolArena", "pool_signature", "paged_step_fn",
 # jitted paged kernels shared across engine instances (jax then caches
 # compilations per pool/table shape)
 _PAGED_FNS: dict = {}
-
-# host params tree -> per-mesh placed copy (weights load once; every
-# engine on the same mesh shares the placed tree). Keyed by object id —
-# the cluster already enforces same-namespace params identity by id.
-_PLACED_PARAMS: dict = {}
 
 
 def mesh_tp(mesh: Mesh, tp_axis: str = "model") -> int:
@@ -91,17 +85,13 @@ def place_params(cfg: ModelConfig, params, mesh: Mesh,
     wq/wk/wv land head-sharded over ``tp_axis``; everything else (embed,
     norms, MLP, wo, head) is replicated — the layout
     :func:`repro.sharding.rules.serve_param_specs` derives from the
-    registry's logical axes. Placement is cached per (params, mesh, axis):
-    replicas sharing one checkpoint share one device copy.
+    registry's logical axes. Every call places a fresh copy; engines share
+    one through :meth:`PagePool.placed_params`.
     """
-    key = (id(params), mesh, tp_axis)
-    if key not in _PLACED_PARAMS:
-        R.validate_serve_tp(cfg, mesh_tp(mesh, tp_axis))
-        specs = R.serve_param_specs(cfg, tp_axis)
-        _PLACED_PARAMS[key] = jax.tree.map(
-            lambda p, s: jax.device_put(p, NamedSharding(mesh, s)),
-            params, specs)
-    return _PLACED_PARAMS[key]
+    R.validate_serve_tp(cfg, mesh_tp(mesh, tp_axis))
+    specs = R.serve_param_specs(cfg, tp_axis)
+    return jax.tree.map(
+        lambda p, s: jax.device_put(p, NamedSharding(mesh, s)), params, specs)
 
 
 def pool_signature(cfg: ModelConfig) -> tuple:
@@ -140,6 +130,9 @@ class PagePool:
         self.n_pages = n_pages
         self.null = n_pages                    # sentinel row, never written
         self._arenas: dict[tuple, PoolArena] = {}
+        # (id(params), mesh, tp_axis) -> (params, placed copy); holding
+        # the host tree pins its id for as long as the entry lives
+        self._placed: dict[tuple, tuple] = {}
         self._refs = np.zeros((n_pages,), np.int32)
         self._free = list(range(n_pages - 1, -1, -1))   # pop() -> 0, 1, 2, ...
         self._owner: dict[int, str | None] = {}
@@ -180,6 +173,19 @@ class PagePool:
                 v = jax.device_put(v, sharding)
             self._arenas[sig] = PoolArena(k, v)
         return self._arenas[sig]
+
+    def placed_params(self, cfg: ModelConfig, params, mesh: Mesh,
+                      tp_axis: str = "model"):
+        """``params`` placed on ``mesh`` (:func:`place_params`), once per
+        (params tree, mesh, axis) for the life of this pool: engines that
+        share the pool on one mesh share one device copy of the weights,
+        and the copy is freed with the pool — a process that builds and
+        drops engines on several meshes does not stack copies on device."""
+        key = (id(params), mesh, tp_axis)
+        if key not in self._placed:
+            self._placed[key] = (params,
+                                 place_params(cfg, params, mesh, tp_axis))
+        return self._placed[key][1]
 
     def bytes_by_device(self) -> dict[str, int]:
         """Real KV bytes resident per device, summed over every arena's
@@ -305,7 +311,7 @@ def _decode_call(cfg: ModelConfig, window: int | None,
             params, cfg, pool_k, pool_v, tables, lengths, tok,
             append_mask=mask, window=window, tp_axis=tp_axis)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(param_specs, pool_spec, pool_spec, rep, rep, rep, rep),
         out_specs=(rep, pool_spec, pool_spec),

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
-from repro.core.compat import shard_map
 
 F32 = jnp.float32
 
@@ -43,7 +41,7 @@ def dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
 def compressed_psum_tree(grads: Any, error: Any, axis: str) -> tuple[Any, Any]:
     """Inside a shard_map manual region: int8-quantized psum over ``axis``
     with error feedback. Returns (reduced fp32 grads, new error state)."""
-    n = compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         g = g.astype(F32) + e.astype(F32)       # apply feedback
@@ -85,7 +83,7 @@ def make_pod_compressed_grad_fn(grad_fn, mesh: Mesh):
             red, new_err = compressed_psum_tree(grads, error, "pod")
             return red, new_err
 
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), P("pod"), P()),
             out_specs=(P(), P()),

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
 
 
 def pipeline_forward(stage_fn: Callable, mesh: Mesh, axis: str = "stage"):
@@ -66,5 +65,5 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh, axis: str = "stage"):
 
     in_specs = (P(axis), P())
     out_specs = P()
-    return shard_map(local, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                      out_specs=out_specs, check_vma=False)

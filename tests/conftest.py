@@ -43,21 +43,10 @@ def shared_jit_cache():
     positions are masked; outputs are unchanged — the bit-identity tests
     hold the padded engines to that.
 
-    jax's on-disk persistent compilation cache is opt-in only
-    (``REPRO_JAX_CACHE_DIR=<dir>``): on this jax/jaxlib CPU build,
-    deserialized executables for the donated training step produce NaNs and
-    then segfault (reproduced via ``launch/train.py --resume``), so it must
-    never be on by default for a repo whose headline claim is bit-identical
-    determinism.
+    jax's on-disk compilation cache is off unless the standard
+    ``JAX_COMPILATION_CACHE_DIR`` variable is set (jax reads it itself);
+    the suite sets no directory of its own.
     """
-    cache_dir = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if cache_dir:
-        import jax
-
-        pathlib.Path(cache_dir).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-
     sys.path.insert(0, str(REPO / "tests"))
     import engine_sim
 

@@ -16,10 +16,7 @@ analogue of the engine suite's:
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from repro.testing.hypo import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from engine_sim import (ClusterSimulator, FakeClock, PowerBudget, Request,
                         Simulator, add_smoke_engine, burst_trace,
@@ -336,8 +333,7 @@ def test_pool_invariants_random_two_tenant_interleaving(codes, n_pages):
     """Random interleaved alloc/adopt/release across two tenants: the free
     list and refcounts never leak or go negative, per-tenant accounting
     sums to the pool's occupancy, and the null sentinel is never a real
-    page. (Runs via hypothesis when installed, repro.testing.hypo
-    otherwise.)"""
+    page."""
     pool = PagePool(n_pages, 4)
     refs: dict[int, int] = {}
     held = {"a": [], "b": []}

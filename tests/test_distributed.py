@@ -10,7 +10,6 @@ def test_compressed_pod_psum_close_to_exact(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.core.compat import shard_map
 from repro.launch.mesh import make_mesh
 from repro.train.compress import compressed_psum_tree, init_error_state
 
@@ -21,10 +20,10 @@ err = init_error_state(g, jnp.float32)
 def inner(g, e):
     return compressed_psum_tree(g, e, "pod")
 
-out, new_err = shard_map(inner, mesh=mesh,
-                         in_specs=(P("pod"), P("pod")),
-                         out_specs=(P("pod"), P("pod")),
-                         check_vma=False)(g, err)
+out, new_err = jax.shard_map(inner, mesh=mesh,
+                             in_specs=(P("pod"), P("pod")),
+                             out_specs=(P("pod"), P("pod")),
+                             check_vma=False)(g, err)
 exact = jnp.mean(g["w"], axis=0, keepdims=True).repeat(2, 0)
 rel = float(jnp.abs(out["w"] - exact).max() / jnp.abs(exact).max())
 assert rel < 0.02, rel          # int8: ~1% worst-case per-tensor error

@@ -6,8 +6,7 @@ real engine/cluster stack:
 * **Conservation** — over any trace, the platform energy integral splits
   exactly: ``total_uj == attributed_uj + overhead_uj`` and
   ``attributed_uj == Σ Request.energy_uj`` over every submitted request
-  (property-tested over randomised op sequences, hypothesis or the
-  seeded in-repo fallback).
+  (property-tested over randomised op sequences with hypothesis).
 * **Observability only** — metering, DVFS points, and idle-bank gating
   change *when* energy is charged, never *what* the engine computes:
   completed tokens are bit-identical to an unmetered run across the
@@ -40,10 +39,7 @@ from repro.serve.loadgen import TenantSpec
 from repro.serve.metrics import SLO, ServeMetrics
 from repro.serve.sampling import SamplingParams
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                      # pragma: no cover - env dependent
-    from repro.testing.hypo import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 TESTS = str(pathlib.Path(__file__).resolve().parent)
 

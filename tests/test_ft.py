@@ -2,10 +2,7 @@
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # fall back to the in-repo seeded-random subset
-    from repro.testing.hypo import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime.ft import FTConfig, FTController, WorkerState
 

@@ -14,20 +14,15 @@ allocator invariants the serving stack leans on after **every** operation:
   alias pages of another, and chains stay parent-contiguous through any
   eviction order.
 
-Runs under real ``hypothesis`` when installed, and otherwise under the
-dependency-free seeded fallback in :mod:`repro.testing.hypo` — the tests
-draw a single integer seed and expand it into an op sequence with
-``random.Random``, the portable subset both providers support.
+The tests draw a single integer seed from ``hypothesis`` and expand it
+into an op sequence with ``random.Random``.
 """
 
 import random
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                    # pragma: no cover
-    from repro.testing.hypo import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve.paged import PagePool
 from repro.serve.pages import PageTable

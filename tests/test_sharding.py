@@ -4,10 +4,7 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # fall back to the in-repo seeded-random subset
-    from repro.testing.hypo import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec
 
 from repro.launch.mesh import make_host_mesh
