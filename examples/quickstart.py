@@ -31,8 +31,8 @@ def main():
     cfg = configs.smoke("granite_3_2b")
     tc = TrainConfig(optimizer="adamw", lr=2e-3, accum=2)
     st = build_sharded_train(cfg, tc, mesh, rules, global_batch=8, seq=64)
-    params = P.cast_tree(P.init_tree(registry.decls(cfg), jax.random.key(0)),
-                         jnp.bfloat16)
+    params = P.init_tree(registry.decls(cfg), jax.random.key(0),
+                         dtype=jnp.bfloat16)
     opt_state = optim_lib.get(tc.optimizer).init(params)
     data = LMPipeline(LMDataConfig(vocab=cfg.vocab, seq=64, global_batch=8,
                                    accum=2))

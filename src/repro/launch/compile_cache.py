@@ -2,10 +2,15 @@
 
 A cold start on the chip spends most of its set-up compiling (tens of
 seconds for one full-width paged decode step). The on-disk cache turns a
-second run's compiles into reads. The cache key includes the directory,
-so the directory must not move between runs: it is the fixed
+later process's compiles into reads, so its directory is the fixed
 ``<checkout>/.jax_cache`` (gitignored), never a temporary, per-process or
 dated path.
+
+An entry is found again only from the same checkout path when the
+program holds a Pallas kernel: JAX strips source locations from the
+module it hashes, but not from the serialized Mosaic body inside the
+kernel's custom call, which names the kernel's source file. Programs
+without a kernel (the parameter init) hit the cache from any path.
 
 The cache is sound on the CPU backend of the installed jaxlib too: a
 training run resumed from a checkpoint with every executable read back

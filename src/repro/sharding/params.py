@@ -55,6 +55,13 @@ def is_axes(x) -> bool:
     return isinstance(x, Axes)
 
 
+def _standard_normal(key: jax.Array, shape) -> jax.Array:
+    # the barrier keeps XLA from folding a caller's scale into the draw's
+    # own constants, so a leaf rounds the same inside a jit as eagerly
+    return jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, jnp.float32))
+
+
 @dataclasses.dataclass(frozen=True)
 class ParamDecl:
     """Declaration of a single parameter tensor."""
@@ -79,11 +86,11 @@ class ParamDecl:
             return jnp.ones(self.shape, self.dtype)
         if self.init == "normal":
             std = self.scale if self.scale is not None else 0.02
-            return (jax.random.normal(key, self.shape, jnp.float32) * std).astype(self.dtype)
+            return (_standard_normal(key, self.shape) * std).astype(self.dtype)
         if self.init == "fan_in":
             fan_in = self.shape[-2] if len(self.shape) >= 2 else self.shape[-1]
             std = 1.0 / math.sqrt(max(fan_in, 1))
-            return (jax.random.normal(key, self.shape, jnp.float32) * std).astype(self.dtype)
+            return (_standard_normal(key, self.shape) * std).astype(self.dtype)
         raise ValueError(f"unknown init {self.init}")
 
     def stacked(self, n: int, axis_name: str | None = None) -> "ParamDecl":
@@ -116,27 +123,25 @@ def axes_tree(tree: Any) -> Any:
 def init_tree(tree: Any, key: jax.Array, dtype=None) -> Any:
     """Materialize a decl tree with per-leaf independent keys.
 
-    ``dtype`` (e.g. bfloat16) materializes every floating leaf directly in
-    that dtype, one compiled program per leaf on the default device: a
-    leaf's float32 draw lives only inside its own program, so no float32
-    copy of the whole tree ever exists (a 2.5 B-parameter model would need
-    10 GB for one). Values match a cast of the float32 tree up to an
-    occasional last-bit difference where XLA fuses the scaling.
+    Each leaf is drawn in its own compiled program on the default device;
+    ``dtype`` (e.g. bfloat16) casts every floating leaf inside that program,
+    so a leaf's float32 draw never outlives it and no float32 copy of the
+    whole tree ever exists (a 2.5 B-parameter model would need 10 GB for
+    one). The values are bit-identical to casting the float32 tree.
     """
     leaves, treedef = jax.tree.flatten(tree, is_leaf=is_decl)
     keys = jax.random.split(key, max(len(leaves), 1))
-    if dtype is None:
-        vals = [d.materialize(k) for d, k in zip(leaves, keys)]
-    else:
-        vals = [_materialize_as(d, k, jnp.dtype(dtype))
-                for d, k in zip(leaves, keys)]
-    return jax.tree.unflatten(treedef, vals)
+    dtype = None if dtype is None else jnp.dtype(dtype)
+    return jax.tree.unflatten(
+        treedef, [_materialize(d, k, dtype) for d, k in zip(leaves, keys)])
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2))
-def _materialize_as(decl: ParamDecl, key: jax.Array, dtype) -> jax.Array:
+def _materialize(decl: ParamDecl, key: jax.Array, dtype) -> jax.Array:
     out = decl.materialize(key)
-    return out.astype(dtype) if jnp.issubdtype(out.dtype, jnp.floating) else out
+    if dtype is None or not jnp.issubdtype(out.dtype, jnp.floating):
+        return out
+    return out.astype(dtype)
 
 
 def count_params(tree: Any) -> int:
@@ -147,9 +152,3 @@ def count_params(tree: Any) -> int:
         total += int(np.prod(shape)) if shape else 1
     return total
 
-
-def cast_tree(params: Any, dtype) -> Any:
-    return jax.tree.map(
-        lambda p: p.astype(dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p,
-        params,
-    )

@@ -5,8 +5,9 @@ runs on a TPU. Its body takes the config, so here it runs under the same
 assertions at ``configs.smoke("granite_3_2b")``: every request completes
 with its token budget in vocab, prefix pages are reused, nothing replays,
 the step holds the Pallas kernel exactly when on a TPU (so not here), and
-the kernel (interpreted) agrees with ``ref`` on one step. The four-chip
-body runs on four virtual CPU devices.
+the kernel (interpreted) agrees with ``ref`` on one step, and the same
+comparison fails a kernel with a planted fault. The four-chip body runs
+on four virtual CPU devices.
 """
 
 import os
@@ -14,7 +15,10 @@ import pathlib
 import subprocess
 import sys
 
+import pytest
+
 from repro import configs
+from repro.kernels.paged_attention import ops as pa_ops
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
@@ -33,6 +37,45 @@ def test_one_chip_body_at_smoke_width():
     assert out["stats"]["completed"] == 8
     assert out["kernel_vs_ref"]["rel_err"] <= chip_smoke.LOGIT_RTOL
     assert any("tpu_custom_call: False" in line for line in lines), lines
+
+
+@pytest.fixture(scope="module")
+def served():
+    """Smoke granite's weights and an engine whose pool holds the pages
+    of the eight requests, as ``kernel_vs_ref`` sees them after serving."""
+    cfg = configs.smoke("granite_3_2b")
+    params = chip_smoke.init_params(cfg, 0)
+    engine = chip_smoke.build_engine(cfg, params)
+    chip_smoke.serve(engine, engine.step,
+                     chip_smoke.make_requests(cfg.vocab, 0))
+    return cfg, params, engine
+
+
+_REAL_KERNEL = pa_ops.paged_attention_kernel
+_PLANTED = {
+    # attends over one position fewer: the token appended this step is lost
+    "drop_appended_token": lambda q, k, v, t, n, **kw: _REAL_KERNEL(
+        q, k, v, t, n - 1, **kw),
+    # table entry 0 read as entry 1: one page of each slot is wrong
+    "wrong_first_page": lambda q, k, v, t, n, **kw: _REAL_KERNEL(
+        q, k, v, t.at[:, 0].set(t[:, 1]), n, **kw),
+    # the last query head's output lost
+    "zero_one_head": lambda q, k, v, t, n, **kw: _REAL_KERNEL(
+        q, k, v, t, n, **kw).at[:, -1].set(0),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(_PLANTED))
+def test_kernel_vs_ref_fails_a_planted_kernel_fault(served, monkeypatch,
+                                                    fault):
+    """The comparison can see a wrong kernel: each planted fault breaks
+    ``LOGIT_RTOL`` over the ``COMPARE_LAYERS`` the chip compares."""
+    cfg, params, engine = served
+    assert chip_smoke.kernel_vs_ref(cfg, params, engine, 0)["rel_err"] \
+        <= chip_smoke.LOGIT_RTOL
+    monkeypatch.setattr(pa_ops, "paged_attention_kernel", _PLANTED[fault])
+    with pytest.raises(AssertionError):
+        chip_smoke.kernel_vs_ref(cfg, params, engine, 0)
 
 
 def test_main_refuses_without_tpu():

@@ -78,7 +78,7 @@ platform = Platform(XHeepConfig())
 rules = platform.rules(mesh)
 tc = TrainConfig(optimizer="adamw", accum=2)
 st = build_sharded_train(cfg, tc, mesh, rules, global_batch=32, seq=32)
-params = P.cast_tree(P.init_tree(registry.decls(cfg), jax.random.key(0)), jnp.bfloat16)
+params = P.init_tree(registry.decls(cfg), jax.random.key(0), dtype=jnp.bfloat16)
 opt = optim_lib.get("adamw").init(params)
 key = jax.random.key(1)
 batch = {"tokens": jax.random.randint(key, (2, 16, 32), 0, cfg.vocab),

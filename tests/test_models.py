@@ -52,7 +52,8 @@ def test_smoke_train_step_no_nans(arch):
     rules = R.fully_connected(mesh)
     tc = TrainConfig(optimizer="adamw", accum=2, lr=1e-3)
     st = build_sharded_train(cfg, tc, mesh, rules, global_batch=4, seq=32)
-    params = P.cast_tree(_init(cfg), jnp.bfloat16)
+    params = P.init_tree(registry.decls(cfg), jax.random.key(0),
+                         dtype=jnp.bfloat16)
     from repro.train import optim as optim_lib
 
     opt = optim_lib.get("adamw").init(params)

@@ -22,7 +22,7 @@ import random
 
 import pytest
 
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.serve.paged import PagePool
 from repro.serve.pages import PageTable
@@ -136,6 +136,7 @@ def _check_table(table: PageTable, pins: dict) -> None:
 
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
+@example(seed=9491)  # over-releases a PageTable pin; replayed on every run
 def test_table_random_ops_preserve_chains_pins_and_namespaces(seed):
     """Random acquire/publish/adopt/claim/evict/release/clear traffic over
     two namespaces and several chains, with a capacity bound forcing LRU

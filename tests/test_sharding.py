@@ -1,6 +1,7 @@
 """Rule-engine unit + property tests (hypothesis)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -111,6 +112,24 @@ def test_init_tree_deterministic_and_independent():
     t2 = init_tree(decls, jax.random.key(0))
     np.testing.assert_array_equal(np.asarray(t1["a"]), np.asarray(t2["a"]))
     assert np.abs(np.asarray(t1["a"]) - np.asarray(t1["b"])).max() > 1e-6
+
+
+def test_init_tree_in_bf16_is_the_cast_float32_tree():
+    """One seed gives one bf16 tree: drawing in bf16 equals casting the
+    float32 draw, bit for bit, and each leaf equals its eager draw."""
+    decls = {"normal": ParamDecl((64, 96), Axes(None, None), scale=0.02),
+             "fan_in": ParamDecl((3, 96, 64), Axes(None, None, None),
+                                 init="fan_in"),
+             "ones": ParamDecl((96,), Axes(None), init="ones")}
+    f32 = init_tree(decls, jax.random.key(5))
+    b16 = init_tree(decls, jax.random.key(5), dtype=jnp.bfloat16)
+    keys = jax.random.split(jax.random.key(5), len(decls))
+    for (name, decl), key in zip(sorted(decls.items()), keys):
+        want = np.asarray(decl.materialize(key))
+        np.testing.assert_array_equal(np.asarray(f32[name]), want)
+        assert b16[name].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(b16[name]), np.asarray(f32[name].astype(jnp.bfloat16)))
 
 
 def test_shard_bytes():
