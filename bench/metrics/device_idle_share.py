@@ -1,0 +1,7 @@
+"""Device: share of the traced window in which no op ran, in percent."""
+
+
+def read(run, variant):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
