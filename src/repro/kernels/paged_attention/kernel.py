@@ -144,6 +144,7 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lengths, *,
 
     return pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=backend.interpret(interpret),

@@ -16,12 +16,18 @@ pool* through the block table. Two backends:
   append scatter is an XLA in-place update on the donated pool under both
   backends.
 
+The append and the attention run under the named scopes ``attn.append``
+and ``attn.kernel``, and the Pallas call is named ``paged_attention``, so
+the compiled step's op metadata says which code each device op came from.
+
 The XAIF contract mirrors the paper's CGRA plug-in: master read ports for
 the query and the two pool planes plus the block table, one master write
 port for O, slave ports = the static page-size/window configuration.
 """
 
 from __future__ import annotations
+
+import jax
 
 from repro.core.power import PowerDomain
 from repro.core.xaif import AcceleratorSpec, PortSpec, register
@@ -64,14 +70,12 @@ def paged_decode_append(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
     backend's ring cache. Returns ``(o, k_pool', v_pool')`` — pass donated
     pools so XLA updates them in place.
     """
-    if impl == "ref":
-        return ref.paged_decode_append(q, k_new, v_new, k_pool, v_pool,
-                                       tables, lengths,
-                                       append_mask=append_mask, window=window)
-    k_pool, v_pool = ref.append_to_tail_pages(k_new, v_new, k_pool, v_pool,
-                                              tables, lengths, append_mask)
-    o = paged_attention(q, k_pool, v_pool, tables, lengths + 1,
-                        window=window, impl=impl, interpret=interpret)
+    with jax.named_scope("attn.append"):
+        k_pool, v_pool = ref.append_to_tail_pages(
+            k_new, v_new, k_pool, v_pool, tables, lengths, append_mask)
+    with jax.named_scope("attn.kernel"):
+        o = paged_attention(q, k_pool, v_pool, tables, lengths + 1,
+                            window=window, impl=impl, interpret=interpret)
     return o, k_pool, v_pool
 
 

@@ -113,18 +113,3 @@ def append_to_tail_pages(k_new, v_new, k_pool, v_pool, tables, lengths,
     v_pool = v_pool.at[page, off].set(v_new.astype(v_pool.dtype), mode="drop")
     return k_pool, v_pool
 
-
-def paged_decode_append(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
-                        append_mask=None, window: int | None = None):
-    """Reference for the fused decode step: append, then attend.
-
-    Writes ``k_new[b]``/``v_new[b]`` into slot ``b``'s tail page at position
-    ``lengths[b]``, then attends over ``lengths[b] + 1`` entries (the last
-    ``window`` of them when windowed). Masked lanes append nothing and
-    their output is garbage (must be ignored). Returns
-    ``(o, k_pool', v_pool')``.
-    """
-    k_pool, v_pool = append_to_tail_pages(k_new, v_new, k_pool, v_pool,
-                                          tables, lengths, append_mask)
-    o = paged_attention(q, k_pool, v_pool, tables, lengths + 1, window=window)
-    return o, k_pool, v_pool

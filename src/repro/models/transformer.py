@@ -346,8 +346,6 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
     x = _embed(params, cfg, tokens[:, None], None, positions)
     il = cfg.moe_interleave if cfg.moe_experts >= 2 else 1
     n_super = cfg.n_layers // il
-    pk = pool_k.reshape(n_super, il, *pool_k.shape[1:])
-    pv = pool_v.reshape(n_super, il, *pool_v.shape[1:])
 
     def block(carry, blk_and_pool):
         x, aux = carry
@@ -356,29 +354,42 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
         for j in range(il):
             lp = blk[f"l{j}"]
             ap = jax.tree.map(lambda a: a.astype(x.dtype), lp["attn"])
-            h = L.rmsnorm(x, ap["ln"], cfg.norm_eps)
-            q, k, v = _project_qkv(h, ap, cfg, positions)
+            with jax.named_scope("attn.qkv"):
+                h = L.rmsnorm(x, ap["ln"], cfg.norm_eps)
+                q, k, v = _project_qkv(h, ap, cfg, positions)
+            # the append and the attention carry the scopes attn.append and
+            # attn.kernel
             o, pk_j, pv_j = paged_ops.paged_decode_append(
                 q[:, 0], k[:, 0], v[:, 0], pk_b[j], pv_b[j], tables, lengths,
                 append_mask=append_mask, window=window, impl=impl)
-            if tp_axis is not None:
-                # (B, H/tp, Dh) per device -> (B, H, Dh), heads in mesh
-                # order = single-device order; wo is replicated, so the
-                # projection below is bitwise the unsharded one
-                o = lax.all_gather(o, tp_axis, axis=1, tiled=True)
-            x = x + jnp.einsum("bshk,hkd->bsd", o[:, None],
-                               ap["wo"].astype(o.dtype))
-            x, a = _ffn(x, lp, cfg, _is_moe_layer(cfg, j))
+            with jax.named_scope("attn.out"):
+                if tp_axis is not None:
+                    # (B, H/tp, Dh) per device -> (B, H, Dh), heads in mesh
+                    # order = single-device order; wo is replicated, so the
+                    # projection below is bitwise the unsharded one
+                    o = lax.all_gather(o, tp_axis, axis=1, tiled=True)
+                x = x + jnp.einsum("bshk,hkd->bsd", o[:, None],
+                                   ap["wo"].astype(o.dtype))
+            with jax.named_scope("mlp"):
+                x, a = _ffn(x, lp, cfg, _is_moe_layer(cfg, j))
             aux = aux + a
             pk_o.append(pk_j)
             pv_o.append(pv_j)
         return (x, aux), (jnp.stack(pk_o), jnp.stack(pv_o))
 
-    (x, _aux), (pk_new, pv_new) = lax.scan(
-        block, (x, jnp.zeros((), jnp.float32)), (params["blocks"], pk, pv))
-    x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _head(params, cfg, x)[:, 0]
-    return (logits, pk_new.reshape(pool_k.shape), pv_new.reshape(pool_v.shape))
+    # the scan's slicing of the pools per layer, and its stacking of the
+    # updated pools, land under "layers"
+    with jax.named_scope("layers"):
+        pk = pool_k.reshape(n_super, il, *pool_k.shape[1:])
+        pv = pool_v.reshape(n_super, il, *pool_v.shape[1:])
+        (x, _aux), (pk_new, pv_new) = lax.scan(
+            block, (x, jnp.zeros((), jnp.float32)), (params["blocks"], pk, pv))
+        pk_new = pk_new.reshape(pool_k.shape)
+        pv_new = pv_new.reshape(pool_v.shape)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _head(params, cfg, x)[:, 0]
+    return logits, pk_new, pv_new
 
 
 def decode_step(params, cfg: ModelConfig, cache: KVCache, tokens):

@@ -89,6 +89,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -99,6 +100,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.runtime.ft import RequestJournal
+from repro.serve import tracing
 from repro.serve.paged import (PagePool, mesh_tp, paged_chunk_fn,
                                paged_step_fn)
 from repro.serve.pages import PageTable
@@ -542,6 +544,7 @@ class ContinuousBatchingEngine:
         self._dirty: set[int] = set()          # lanes holding a dead cache page
         self._seq = 0
         self._pending: tuple[_StepMeta, Any] | None = None  # unretired step
+        self._trace: tracing.StepTrace | None = None  # step() in progress
         self._prev_nxt = None                  # device argmax of pending step
 
         # throughput counters — monotone by construction
@@ -652,8 +655,10 @@ class ContinuousBatchingEngine:
         self.queue.append(request)
         return True
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Admit queue heads into free slots; returns how many."""
         free = [i for i in range(self.n_slots) if self.slots[i] is None]
+        admitted = 0
         while self.queue and free:
             i = self._place(free)
             if i is SHED:
@@ -668,6 +673,8 @@ class ContinuousBatchingEngine:
             free.remove(i)
             req = self.queue.popleft()              # FIFO — fairness invariant
             self._admit_into(i, req)
+            admitted += 1
+        return admitted
 
     def _place(self, free: list[int]):
         """First free slot the scheduler lets the queue head into (None =
@@ -780,15 +787,38 @@ class ContinuousBatchingEngine:
         sibling computing the same page (dedup stall). With async dispatch
         the launch happens before the *previous* step's host bookkeeping,
         so the device never idles on the host. Returns False when idle.
+
+        A step that does work logs one ``StepTrace`` and a profiler span
+        per phase (:mod:`repro.serve.tracing`).
         """
+        rec = tracing.StepTrace(self.name, time.perf_counter())
+        generated = self.tokens_generated
+        prompt_fed = self.prompt_tokens_processed
+        self._trace = rec
+        try:
+            with jax.profiler.TraceAnnotation("engine.step"):
+                worked = self._step(rec)
+        finally:
+            self._trace = None
+        if worked:
+            rec.end = time.perf_counter()
+            rec.emitted = self.tokens_generated - generated
+            rec.prompt_fed = self.prompt_tokens_processed - prompt_fed
+            tracing.record(rec)
+        return worked
+
+    def _step(self, rec: tracing.StepTrace) -> bool:
         if self._faulted:
             # quarantine left by a preemption's pending-flush: recover
             # before dispatch — the faulted slot's next_token is stale
             self._recover_faulted()
         if self._meter is not None:
-            residents, idle_banks = self._meter_residents()
-            self._meter.tick(self.clock(), residents, idle_banks)
-        self._admit()
+            with tracing.span("engine.meter", rec):
+                residents, idle_banks = self._meter_residents()
+                self._meter.tick(self.clock(), residents, idle_banks)
+        with tracing.span("engine.admit", rec):
+            rec.admitted = self._admit()
+        rec.queued = len(self.queue)
         if self.active == 0:
             if self._pending is not None:
                 self._retire(self._pending)        # drain the in-flight step
@@ -814,6 +844,64 @@ class ContinuousBatchingEngine:
     def _dispatch(self) -> tuple[_StepMeta, Any]:
         """Build this step's batch, launch it, and do all host bookkeeping
         that does not need the step's token values (those retire later)."""
+        rec = self._trace
+        with tracing.span("engine.batch", rec):
+            toks, counts, feedback, emit = self._batch(rec)
+        with tracing.span("engine.tables", rec):
+            tables = self._build_tables() if self.paged else None
+        with tracing.span("engine.launch", rec):
+            nxt = self._launch(toks, counts, feedback, emit, tables)
+        if rec is not None:
+            rec.launched = time.perf_counter()
+        with tracing.span("engine.account", rec):
+            meta = _StepMeta([], [])
+            if self._meter is not None:
+                occupied = {self._slot_bank[i]
+                            for i, s in enumerate(self.slots) if s is not None}
+                launch_idle_banks = len(set(self._slot_bank)) - len(occupied)
+                charges = []
+            for i, slot in enumerate(self.slots):
+                if slot is None:
+                    continue
+                c = int(counts[i])
+                was_prefilling = slot.prefilling
+                if was_prefilling and c == 0:
+                    continue               # stalled this step
+                if self._meter is not None:
+                    charges.append((slot.request,
+                                    "prefill" if was_prefilling else "decode",
+                                    c, self._page_share(slot)))
+                slot.fed += c
+                if self.paged:
+                    self._recycle_dead(slot)   # window crossed: free dead
+                if was_prefilling:
+                    self.prompt_tokens_processed += c
+                    self._maybe_publish(i, slot)
+                    if slot.prefilling:
+                        # still consuming the prompt: teacher-force the next
+                        # token
+                        slot.next_token = slot.request.prompt[slot.fed]
+                        continue
+                    self._drop_claims(slot)    # prefill done; nothing to claim
+                meta.emitted.append((i, slot))
+                slot.produced += 1
+                self.tokens_generated += 1
+                if slot.produced >= slot.request.max_new_tokens:
+                    # the lane is host-known complete the moment the step is
+                    # dispatched (greedy decode emits exactly one token per
+                    # step); free it now so the next admission overlaps with
+                    # the in-flight computation — the token value lands at
+                    # retire
+                    meta.finished.append(slot)
+                    self._evict(i)
+        if self._meter is not None:
+            with tracing.span("engine.meter", rec):
+                self._meter.charge_step(charges, launch_idle_banks)
+        return meta, nxt
+
+    def _batch(self, rec: tracing.StepTrace | None):
+        """This step's host inputs — tokens, per-lane counts, feedback and
+        emit flags — with the pages they will write allocated."""
         chunk = self.prefill_chunk
         n = self.n_lanes
         toks = np.full((n, chunk), self.pad_token, np.int32)
@@ -827,6 +915,7 @@ class ContinuousBatchingEngine:
         emit = np.zeros((n,), bool)
         pending_emit = ({i: s for i, s in self._pending[0].emitted}
                         if self._pending is not None else {})
+        decode_fed = 0
 
         for i, slot in enumerate(self.slots):
             if slot is None:
@@ -849,59 +938,21 @@ class ContinuousBatchingEngine:
             else:
                 counts[i] = 1
                 emit[i] = True
+                decode_fed += 1
                 if self.async_dispatch and pending_emit.get(i) is slot:
                     feedback[i] = True     # token rides on-device from step N
                 else:
                     toks[i, 0] = slot.next_token
             if self.paged and counts[i]:
                 self._ensure_pages(slot, slot.fed + int(counts[i]))
+        if rec is not None:
+            rec.decode_fed = decode_fed
+        return toks, counts, feedback, emit
 
-        nxt = self._launch(toks, counts, feedback, emit)
-        meta = _StepMeta([], [])
-        if self._meter is not None:
-            occupied = {self._slot_bank[i]
-                        for i, s in enumerate(self.slots) if s is not None}
-            launch_idle_banks = len(set(self._slot_bank)) - len(occupied)
-            charges = []
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            c = int(counts[i])
-            was_prefilling = slot.prefilling
-            if was_prefilling and c == 0:
-                continue                   # stalled this step
-            if self._meter is not None:
-                charges.append((slot.request,
-                                "prefill" if was_prefilling else "decode",
-                                c, self._page_share(slot)))
-            slot.fed += c
-            if self.paged:
-                self._recycle_dead(slot)   # window crossed: free dead blocks
-            if was_prefilling:
-                self.prompt_tokens_processed += c
-                self._maybe_publish(i, slot)
-                if slot.prefilling:
-                    # still consuming the prompt: teacher-force the next token
-                    slot.next_token = slot.request.prompt[slot.fed]
-                    continue
-                self._drop_claims(slot)    # prefill done; nothing left to claim
-            meta.emitted.append((i, slot))
-            slot.produced += 1
-            self.tokens_generated += 1
-            if slot.produced >= slot.request.max_new_tokens:
-                # the lane is host-known complete the moment the step is
-                # dispatched (greedy decode emits exactly one token per
-                # step); free it now so the next admission overlaps with the
-                # in-flight computation — the token value lands at retire
-                meta.finished.append(slot)
-                self._evict(i)
-        if self._meter is not None:
-            self._meter.charge_step(charges, launch_idle_banks)
-        return meta, nxt
-
-    def _launch(self, toks, counts, feedback, emit):
+    def _launch(self, toks, counts, feedback, emit, tables):
         """One batched device launch; returns the on-device next-token vec
-        (sampled per lane — exact argmax for greedy lanes)."""
+        (sampled per lane — exact argmax for greedy lanes). ``tables`` is
+        the paged backend's (block tables, lengths) on the device."""
         if self.chaos is not None:
             # fault-injection point, deliberately before any buffer is
             # donated: a DeviceStepFault here leaves device and host
@@ -918,7 +969,7 @@ class ContinuousBatchingEngine:
         tp = jnp.asarray(self._topp)
         if self.paged:
             arena = self._arena
-            tables, lengths = self._build_tables()
+            tables, lengths = tables
             if chunk == 1 or int(counts.max()) <= 1:
                 nxt, arena.k, arena.v, self._keys = self._pstep(
                     self.params, arena.k, arena.v, tables, lengths,
@@ -963,43 +1014,49 @@ class ContinuousBatchingEngine:
         so delivering would corrupt the record's sequence.
         """
         meta, nxt = pending
-        vals = np.asarray(jax.device_get(nxt)).reshape(-1)
-        now = self.clock()
-        for i, slot in meta.emitted:
-            if id(slot) in self._skip_retire:
-                continue
-            tok = int(vals[i])
-            if self.chaos is not None:
-                tok = self.chaos.deliver_token(self.name, tok)
-            ok = 0 <= tok < self.cfg.vocab
-            if ok:
-                try:
-                    self.journal.record_token(slot.request.id, tok)
-                except RuntimeError:
-                    # replay cross-check divergence: an in-range corrupt
-                    # token caught against the journaled prior run
-                    ok = False
-            if not ok:
-                self.token_faults += 1
-                self._faulted.append(slot)
-                continue
-            if slot.request.first_token_time is None:
-                slot.request.first_token_time = now   # TTFT stamp (at retire:
-                # the token is host-visible only once the transfer lands)
-            slot.request.tokens.append(tok)
-            slot.next_token = tok
-        faulted_ids = {id(s) for s in self._faulted}
-        for slot in meta.finished:
-            if id(slot) in self._skip_retire or id(slot) in faulted_ids:
-                continue               # quarantined: must replay, not finish
-            req = slot.request
-            req.finish_time = self.clock()
-            self.journal.complete(req.id)
-            self.completed.append(req)
-            # XAIF end-of-computation interrupt, then the per-request handler
-            self.platform.interrupts.fire(COMPLETE_LINE, req)
-            if req.on_complete is not None:
-                req.on_complete(req)
+        with tracing.span("engine.wait", self._trace):
+            vals = np.asarray(jax.device_get(nxt)).reshape(-1)
+        if self._trace is not None:
+            self._trace.waited = time.perf_counter()
+        with tracing.span("engine.retire", self._trace):
+            now = self.clock()
+            for i, slot in meta.emitted:
+                if id(slot) in self._skip_retire:
+                    continue
+                tok = int(vals[i])
+                if self.chaos is not None:
+                    tok = self.chaos.deliver_token(self.name, tok)
+                ok = 0 <= tok < self.cfg.vocab
+                if ok:
+                    try:
+                        self.journal.record_token(slot.request.id, tok)
+                    except RuntimeError:
+                        # replay cross-check divergence: an in-range corrupt
+                        # token caught against the journaled prior run
+                        ok = False
+                if not ok:
+                    self.token_faults += 1
+                    self._faulted.append(slot)
+                    continue
+                if slot.request.first_token_time is None:
+                    # TTFT stamp (at retire: the token is host-visible only
+                    # once the transfer lands)
+                    slot.request.first_token_time = now
+                slot.request.tokens.append(tok)
+                slot.next_token = tok
+            faulted_ids = {id(s) for s in self._faulted}
+            for slot in meta.finished:
+                if id(slot) in self._skip_retire or id(slot) in faulted_ids:
+                    continue           # quarantined: must replay, not finish
+                req = slot.request
+                req.finish_time = self.clock()
+                self.journal.complete(req.id)
+                self.completed.append(req)
+                # XAIF end-of-computation interrupt, then the request's
+                # handler
+                self.platform.interrupts.fire(COMPLETE_LINE, req)
+                if req.on_complete is not None:
+                    req.on_complete(req)
 
     def _recover_faulted(self) -> None:
         """Quarantine recovery: replay every corruption-faulted request.
@@ -1025,30 +1082,31 @@ class ContinuousBatchingEngine:
                     self._skip_retire = frozenset()
                 self._pending = None
                 self._prev_nxt = None
-            requeue = []
-            for slot in sorted(batch, key=lambda s: s.seq):
-                req = slot.request
-                n = self._replay_counts.get(req.id, 0) + 1
-                self._replay_counts[req.id] = n
-                if n > self.max_replays:
-                    raise RuntimeError(
-                        f"request {req.id!r} quarantined {n} times — "
-                        "persistent divergence, not transient corruption")
-                for i, s in enumerate(self.slots):
-                    if s is slot:
-                        self._evict(i)
-                        break
-                # a host-known-finished slot was already evicted at
-                # dispatch; a preemption racing the quarantine may have
-                # requeued the request itself — never queue it twice
-                if any(r is req for r in self.queue):
-                    continue
-                req.tokens = []
-                req.admit_time = None
-                req.first_token_time = req.finish_time = None
-                requeue.append(req)
-                self.replays += 1
-            self.queue.extendleft(reversed(requeue))
+            with tracing.span("engine.recover", self._trace):
+                requeue = []
+                for slot in sorted(batch, key=lambda s: s.seq):
+                    req = slot.request
+                    n = self._replay_counts.get(req.id, 0) + 1
+                    self._replay_counts[req.id] = n
+                    if n > self.max_replays:
+                        raise RuntimeError(
+                            f"request {req.id!r} quarantined {n} times — "
+                            "persistent divergence, not transient corruption")
+                    for i, s in enumerate(self.slots):
+                        if s is slot:
+                            self._evict(i)
+                            break
+                    # a host-known-finished slot was already evicted at
+                    # dispatch; a preemption racing the quarantine may have
+                    # requeued the request itself — never queue it twice
+                    if any(r is req for r in self.queue):
+                        continue
+                    req.tokens = []
+                    req.admit_time = None
+                    req.first_token_time = req.finish_time = None
+                    requeue.append(req)
+                    self.replays += 1
+                self.queue.extendleft(reversed(requeue))
 
     # -- paged-backend plumbing ----------------------------------------------
 

@@ -353,9 +353,10 @@ def paged_step_fn(cfg: ModelConfig, window: int | None = None,
             tok = jnp.where(feedback, prev, toks)
             logits, pool_k, pool_v = decode(
                 params, pool_k, pool_v, tables, lengths, tok, mask)
-            carry, use = split_keys(keys)
-            nxt = jax.vmap(sample)(logits, use, temp, top_k, top_p)
-            keys = jnp.where(emit[:, None], carry, keys)
+            with jax.named_scope("sample"):
+                carry, use = split_keys(keys)
+                nxt = jax.vmap(sample)(logits, use, temp, top_k, top_p)
+                keys = jnp.where(emit[:, None], carry, keys)
             return nxt, pool_k, pool_v, keys
 
         _PAGED_FNS[key] = jax.jit(step, donate_argnums=(1, 2, 10))
@@ -395,8 +396,9 @@ def paged_chunk_fn(cfg: ModelConfig, chunk: int, window: int | None = None,
                 logits, pool_k, pool_v = decode(
                     params, pool_k, pool_v, tables, lengths + j, tok,
                     j < counts)
-                return ((pool_k, pool_v),
-                        jax.vmap(sample)(logits, use, temp, top_k, top_p))
+                with jax.named_scope("sample"):
+                    nxt = jax.vmap(sample)(logits, use, temp, top_k, top_p)
+                return (pool_k, pool_v), nxt
 
             (pool_k, pool_v), outs = lax.scan(
                 body, (pool_k, pool_v),

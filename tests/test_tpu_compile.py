@@ -15,6 +15,7 @@ every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -69,4 +70,8 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, heads, kv_heads,
                                       window=window, interpret=False)
 
     compiled = jax.jit(step).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the call is named, so a profile tells it from any other Pallas call
+    assert re.search(r"%paged_attention[.\d]* = .*"
+                     r'custom_call_target="tpu_custom_call"', text)
