@@ -1193,9 +1193,9 @@ def run_kernel_bench(cfg, args) -> tuple[dict, float]:
     np_slot = -(-args.max_len // ps)
     pool_pages = b * np_slot + 1
     q = jax.numpy.asarray(rng.normal(size=(b, h, d)), jax.numpy.float32)
-    kp = jax.numpy.asarray(rng.normal(size=(pool_pages, ps, kh, d)),
+    kp = jax.numpy.asarray(rng.normal(size=(pool_pages, ps, kh * d)),
                            jax.numpy.float32)
-    vp = jax.numpy.asarray(rng.normal(size=(pool_pages, ps, kh, d)),
+    vp = jax.numpy.asarray(rng.normal(size=(pool_pages, ps, kh * d)),
                            jax.numpy.float32)
     tables = jax.numpy.asarray(
         rng.permutation(pool_pages - 1)[:b * np_slot].reshape(b, np_slot),

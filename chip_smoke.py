@@ -156,14 +156,16 @@ def check_served(requests, stats: dict, vocab: int) -> None:
 
 def _random_step_inputs(cfg, n_pages: int, seed: int, pool_k=None,
                         pool_v=None):
-    """Tables, lengths and tokens for one full-width paged step over
-    ``n_pages`` pool pages (random bf16 K/V when no pool is given)."""
+    """Tables, lengths and tokens for one full-width paged step whose
+    tables pick among the first ``n_pages`` pages of each layer (random
+    bf16 K/V in a flat arena of ``n_pages`` a layer when no pool is
+    given)."""
     rng = np.random.default_rng(seed)
     n_entries = -(-MAX_LEN // PAGE_SIZE)
     if pool_k is None:
         kk, kv = jax.random.split(jax.random.key(seed))
-        shape = (cfg.n_layers, n_pages, PAGE_SIZE, cfg.n_kv_heads,
-                 cfg.resolved_head_dim)
+        shape = (cfg.n_layers * n_pages, PAGE_SIZE,
+                 cfg.n_kv_heads * cfg.resolved_head_dim)
         pool_k = jax.random.normal(kk, shape, jnp.bfloat16)
         pool_v = jax.random.normal(kv, shape, jnp.bfloat16)
     tables = jnp.asarray(rng.integers(0, n_pages, (SLOTS, n_entries)),
@@ -199,9 +201,10 @@ def kernel_vs_ref(cfg, params, engine, seed: int) -> dict:
     logits must agree within LOGIT_RTOL."""
     used = max(engine._pool.stats["high_water"], 1)
     cfg, params = first_layers(cfg, params)
+    # the arena's rows of the first layers: n_pages + 1 (the null) a layer
+    rows = cfg.n_layers * (engine._pool.n_pages + 1)
     pool_k, pool_v, tables, lengths, toks = _random_step_inputs(
-        cfg, used, seed, engine._arena.k[:cfg.n_layers],
-        engine._arena.v[:cfg.n_layers])
+        cfg, used, seed, engine._arena.k[:rows], engine._arena.v[:rows])
     logits = {}
     for impl in ("pallas", "ref"):
         fn = jax.jit(lambda p, k, v, t, n, x, impl=impl:

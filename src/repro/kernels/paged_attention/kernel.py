@@ -14,11 +14,26 @@ Grid = (slots, n_pages); the page dimension is innermost and sequential, and
 the running (m, l, acc) online-softmax state is carried across it in VMEM
 scratch, exactly like the flash kernel carries its KV-tile loop.
 
-Layout contract: q (B, H, D); k/v pool (P, page_size, K, D); tables (B, NP)
-int32 page ids; lengths (B,) int32 valid-position counts. GQA is folded
-head-major: head h reads KV head ``h // (H // K)``. H and K are read off
-the operand shapes, so the kernel serves a tensor-parallel head slice
-(H/tp, K/tp inside ``shard_map``) exactly like the full head set.
+Layout contract: q (B, H, D); k/v pool (P', page_size, K·D) — the flat,
+lane-dense page space of the serving arena, each position's K heads
+head-major along the lanes (head ``kh`` at ``[kh·D, (kh+1)·D)``); tables
+(B, NP) int32 rows of that space; lengths (B,) int32 valid-position counts.
+The arena holds every layer's pages in one space (layer ``l``'s page ``p``
+is row ``l·P + p``), so the caller picks the layer by offsetting the
+tables by ``l·P`` and the kernel reads the arena where it lies, with no
+per-layer slice. H and D come from q and K = lanes // D from the pool, so
+the kernel serves a tensor-parallel head slice (H/tp, K/tp inside
+``shard_map``) exactly like the full head set.
+
+GQA is folded head-major: head h reads KV head ``h // (H // K)``. Each
+grid step streams one page of all K heads as a ``(page_size, K·D)`` block
+and attends it with a **block-diagonal** query: row h of the (H, K·D)
+query holds q[h] in its KV head's lanes and zeros elsewhere, so one
+(H, K·D) x (K·D, page_size) matmul gives every head's scores against its
+own KV head, exactly. ``p @ v`` then gives (H, K·D), of which each head
+keeps its own head's lanes; the wrapper takes that diagonal after the
+call. The zeros cost K times the MXU work of a page, which is small
+against the per-step cost at decode's one query a slot.
 
 With ``window`` set the table is a **ring block table** (the sliding-window
 serving layout): entry ``e`` holds the page of the newest block
@@ -49,7 +64,7 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size: int, groups: int,
+                  m_scr, l_scr, acc_scr, *, page_size: int,
                   window: int | None, scale: float):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -62,17 +77,13 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     kv_len = lengths_ref[b]
-    q = q_ref[0].astype(jnp.float32)               # (H, D)
-    k = k_ref[0].astype(jnp.float32)               # (ps, K, D)
-    v = v_ref[0].astype(jnp.float32)
-    h, d = q.shape
-    kh = k.shape[1]
-
-    # GQA head-major fold: (H, D) -> (K, G, D); batch the KV-head axis
-    qf = q.reshape(kh, groups, d)
-    s = lax.dot_general(qf, k, (((2,), (2,)), ((0,), (1,))),
-                        preferred_element_type=jnp.float32) * scale  # (K,G,ps)
-    s = s.reshape(h, page_size)
+    q = q_ref[0]                                   # (H, K·D) block-diagonal
+    k = k_ref[0]                                   # (ps, K·D)
+    # bf16 products are exact in f32, so scores accumulate in f32 as if
+    # the operands had been widened first
+    dt = jnp.promote_types(q.dtype, k.dtype)
+    s = lax.dot_general(q.astype(dt), k.astype(dt), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale  # (H,ps)
 
     if window is not None:
         # ring block table: entry j holds the newest block b ≡ j (mod n_p)
@@ -92,10 +103,9 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-    pf = p.reshape(kh, groups, page_size)
-    pv = lax.dot_general(pf, v, (((2,), (0,)), ((0,), (1,))),
-                         preferred_element_type=jnp.float32)  # (K, G, D)
-    acc_scr[...] = acc_scr[...] * corr + pv.reshape(h, d)
+    pv = jnp.dot(p, v_ref[0].astype(jnp.float32),
+                 preferred_element_type=jnp.float32)         # (H, K·D)
+    acc_scr[...] = acc_scr[...] * corr + pv
     m_scr[...] = m_new
 
     @pl.when(j == n_p - 1)
@@ -114,38 +124,48 @@ def paged_attention_kernel(q, k_pool, v_pool, tables, lengths, *,
     unpadded head dim's scale explicitly when D is padded for the MXU.
     """
     b, h, d = q.shape
-    n_pages, ps, kh, dk = k_pool.shape
-    assert dk == d, (dk, d)
-    assert h % kh == 0, (h, kh)
+    _, ps, lanes = k_pool.shape
+    kh = lanes // d
+    assert kh * d == lanes and h % kh == 0, (h, d, lanes)
     groups = h // kh
     np_per_slot = tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
-    kernel = functools.partial(_paged_kernel, page_size=ps, groups=groups,
-                               window=window, scale=scale)
+    # row h carries q[h] in the lanes of its KV head h // groups
+    eye = jnp.eye(kh, dtype=q.dtype)
+    q_bd = jnp.einsum("bkgd,kj->bkgjd", q.reshape(b, kh, groups, d),
+                      eye).reshape(b, h, lanes)
+
+    kernel = functools.partial(_paged_kernel, page_size=ps, window=window,
+                               scale=scale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, np_per_slot),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda bi, j, t, ln: (bi, 0, 0)),
-            pl.BlockSpec((1, ps, kh, d),
-                         lambda bi, j, t, ln: (t[bi, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, kh, d),
-                         lambda bi, j, t, ln: (t[bi, j], 0, 0, 0)),
+            pl.BlockSpec((1, h, lanes), lambda bi, j, t, ln: (bi, 0, 0)),
+            pl.BlockSpec((1, ps, lanes),
+                         lambda bi, j, t, ln: (t[bi, j], 0, 0)),
+            pl.BlockSpec((1, ps, lanes),
+                         lambda bi, j, t, ln: (t[bi, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, d), lambda bi, j, t, ln: (bi, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, lanes), lambda bi, j, t, ln: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, lanes), jnp.float32),
         ],
     )
 
-    return pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         name="paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, lanes), q.dtype),
         interpret=backend.interpret(interpret),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pool, v_pool)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q_bd, k_pool,
+      v_pool)
+    # each head keeps the lanes of its own KV head: the block diagonal
+    o = o.reshape(b, kh, groups, kh, d)
+    return jnp.diagonal(o, axis1=1, axis2=3).transpose(0, 3, 1, 2).reshape(
+        b, h, d)

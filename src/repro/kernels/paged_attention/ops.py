@@ -16,6 +16,13 @@ pool* through the block table. Two backends:
   append scatter is an XLA in-place update on the donated pool under both
   backends.
 
+The pools are the flat, lane-dense page space ``(P', ps, K·D)`` of the
+serving arena (K heads head-major along the lanes). The arena holds every
+layer's pages — layer ``l``'s page ``p`` is row ``l·P + p`` — and the
+caller hands the ops tables offset by ``l·P``; the ops see one page space
+and know nothing of layers, so the append scatters into the whole donated
+arena in place and the kernel reads it there.
+
 The append and the attention run under the named scopes ``attn.append``
 and ``attn.kernel``, and the Pallas call is named ``paged_attention``, so
 the compiled step's op metadata says which code each device op came from.
@@ -40,9 +47,10 @@ from repro.sharding.params import Axes
 def paged_attention(q, k_pool, v_pool, tables, lengths, *,
                     window: int | None = None, impl: str = "ref",
                     interpret: bool | None = None):
-    """Single-query paged attention over a (P, ps, K, D) page pool.
+    """Single-query paged attention over a flat (P', ps, K·D) page space.
 
-    q (B, H, D); tables (B, NP) int32 page ids; lengths (B,) valid counts.
+    q (B, H, D); tables (B, NP) int32 rows of the page space (layer ``l``
+    of the arena: page ids offset by ``l·P``); lengths (B,) valid counts.
     With ``window`` the tables carry **ring** semantics — entry ``e`` holds
     the newest block ``b ≡ e (mod NP)`` — and only the last ``window``
     positions attend; see the ``kernel.py``/``ref.py`` module docstrings.
@@ -61,8 +69,9 @@ def paged_decode_append(q, k_new, v_new, k_pool, v_pool, tables, lengths, *,
                         impl: str = "ref", interpret: bool | None = None):
     """Fused decode step: append the new KV entry, attend over it in place.
 
-    Appends ``k_new[b]``/``v_new[b]`` at position ``lengths[b]`` of slot
-    ``b``'s page chain (``append_mask`` False drops the append — the lane is
+    Appends ``k_new[b]``/``v_new[b]`` (B, K, D), as K·D head-major lanes,
+    at position ``lengths[b]`` of slot ``b``'s page chain in the flat pools
+    (P', ps, K·D) (``append_mask`` False drops the append — the lane is
     riding the batch idle and its output is ignored), then attends over
     ``lengths[b] + 1`` positions. With ``window`` the block tables are ring
     tables (the tail entry wraps modulo the table width) and attention
@@ -89,8 +98,9 @@ SPEC = AcceleratorSpec(
     ),
     master_ports=(
         PortSpec("q", Axes(lx.DECODE_BATCH, lx.HEADS, lx.HEAD_DIM)),
-        PortSpec("k_pool", Axes(None, lx.CACHE_SEQ, lx.KV_HEADS, lx.HEAD_DIM)),
-        PortSpec("v_pool", Axes(None, lx.CACHE_SEQ, lx.KV_HEADS, lx.HEAD_DIM)),
+        # the flat arena's lanes are KV heads x head_dim, head-major
+        PortSpec("k_pool", Axes(None, lx.CACHE_SEQ, lx.KV_HEADS)),
+        PortSpec("v_pool", Axes(None, lx.CACHE_SEQ, lx.KV_HEADS)),
         PortSpec("block_table", Axes(lx.DECODE_BATCH, None), dtype="int32"),
         PortSpec("o", Axes(lx.DECODE_BATCH, lx.HEADS, lx.HEAD_DIM)),
     ),

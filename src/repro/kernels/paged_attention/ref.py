@@ -28,16 +28,24 @@ cache, not merely allclose. That holds for both table layouts:
   is the degenerate ring (no entry is ever reused), so callers with
   un-recycled tables can pass ``window`` unchanged.
 
+Layout: the pool is **flat and lane-dense**, ``(P', ps, K·D)``: page ``p``
+holds ``ps`` positions, each a row of ``K·D`` lanes, head-major (KV head
+``kh`` sits at lanes ``[kh·D, (kh+1)·D)``). The serving arena stacks every
+layer's pages in one such page space — layer ``l``'s page ``p`` is row
+``l·P + p`` — so a caller attends layer ``l`` by offsetting the block table
+by ``l·P``; the ops themselves know nothing of layers. ``K`` is read off
+the shapes, ``pool.shape[-1] // q.shape[-1]``.
+
 Tensor parallelism: every head count here is read off the operand shapes
-(``H`` from q, ``K`` from the pool; GQA groups = H // K), never from a
-config — so the same code runs unchanged inside ``shard_map`` on a
-per-device head slice (H/tp query heads against a pool arena holding
-Kh/tp KV heads). Each query head attends only to its own KV head, so a
-head slice's output block is bitwise the same rows of the full-H result;
-the serving layer all-gathers the blocks before the output projection
-(see ``transformer.decode_step_paged``). The Pallas kernel shares the
-shape-polymorphic contract, keeping ref/pallas parity checks valid per
-device slice too.
+(``H`` from q, ``K`` from the pool's lanes; GQA groups = H // K), never
+from a config — so the same code runs unchanged inside ``shard_map`` on a
+per-device head slice (H/tp query heads against a pool arena holding the
+lanes of Kh/tp KV heads). Each query head attends only to its own KV
+head, so a head slice's output block is bitwise the same rows of the
+full-H result; the serving layer all-gathers the blocks before the output
+projection (see ``transformer.decode_step_paged``). The Pallas kernel
+shares the shape-polymorphic contract, keeping ref/pallas parity checks
+valid per device slice too.
 """
 
 from __future__ import annotations
@@ -53,7 +61,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     """Single-query paged attention, pure-jax reference.
 
     q: (B, H, D) — one post-rope query per slot.
-    k_pool/v_pool: (P, ps, K, D) — the global page pool (one layer).
+    k_pool/v_pool: (P', ps, K·D) — the flat page space, head-major lanes
+        (the tables pick the layer's rows; see the module docstring).
     tables: (B, NP) int32 — page ids per slot; unused entries must point at
         pages whose positions are masked (beyond ``lengths[b]``, or outside
         the window), so their contents are never observable.
@@ -66,7 +75,9 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
         buffer / ring buffer) and run the same ``attention_chunked``, so
         either way the result is bit-identical to the lane decode.
     """
-    _, ps, kh, d = k_pool.shape
+    d = q.shape[-1]
+    _, ps, lanes = k_pool.shape
+    kh = lanes // d
     n_entries = tables.shape[1]
 
     def one(qb, tb, lb):
@@ -77,8 +88,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
             p = i + ((lb - 1 - i) // window) * window
             p = jnp.maximum(p, 0)          # i >= lb lanes: masked by kv_len
             entry = (p // ps) % n_entries  # ring block-table mapping
-            kg = k_pool[tb[entry], p % ps]           # (window, K, D)
-            vg = v_pool[tb[entry], p % ps]
+            kg = k_pool[tb[entry], p % ps].reshape(window, kh, d)
+            vg = v_pool[tb[entry], p % ps].reshape(window, kh, d)
             kv_len = jnp.minimum(lb, window)
             return attention_chunked(qb[None, None], kg[None], vg[None],
                                      causal=False, kv_len=kv_len)[0, 0]
@@ -94,7 +105,8 @@ def append_to_tail_pages(k_new, v_new, k_pool, v_pool, tables, lengths,
                          append_mask=None):
     """Scatter each slot's new KV entry into its tail page, in place.
 
-    The entry lands at page ``tables[b, (lengths[b] // ps) % NP]``, row
+    The entry (``k_new[b]`` of shape (K, D), stored as its K·D head-major
+    lanes) lands at page ``tables[b, (lengths[b] // ps) % NP]``, row
     ``lengths[b] % ps`` — the ``% NP`` makes the same code serve contiguous
     tables (where ``lengths // ps < NP`` always) and ring tables (where the
     tail block's entry wraps). ``append_mask`` (B,) bool drops masked
@@ -109,7 +121,10 @@ def append_to_tail_pages(k_new, v_new, k_pool, v_pool, tables, lengths,
     off = lengths % ps
     if append_mask is not None:
         page = jnp.where(append_mask, page, n_pages)
-    k_pool = k_pool.at[page, off].set(k_new.astype(k_pool.dtype), mode="drop")
-    v_pool = v_pool.at[page, off].set(v_new.astype(v_pool.dtype), mode="drop")
+    lanes = k_pool.shape[-1]
+    k_pool = k_pool.at[page, off].set(
+        k_new.reshape(b, lanes).astype(k_pool.dtype), mode="drop")
+    v_pool = v_pool.at[page, off].set(
+        v_new.reshape(b, lanes).astype(v_pool.dtype), mode="drop")
     return k_pool, v_pool
 

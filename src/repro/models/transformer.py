@@ -287,13 +287,21 @@ def supports_paged(cfg: ModelConfig) -> bool:
 
 def paged_pool_init(cfg: ModelConfig, n_pages: int, page_size: int,
                     dtype=jnp.bfloat16):
-    """Zeroed global KV page pool: a (k, v) pair of (L, P, ps, Kh, Dh).
+    """Zeroed global KV arena: a (k, v) pair of (L·P, ps, Kh·Dh).
 
-    ``n_pages`` includes any null/sentinel pages the caller reserves; the
-    pool carries no per-slot structure — block tables impose it per step.
+    One flat, lane-dense page space for every layer: layer ``l``'s page
+    ``p`` is row ``l·P + p`` (``P = n_pages``), and a position's KV heads lie
+    head-major along the last axis (head ``kh`` at lanes ``[kh·Dh,
+    (kh+1)·Dh)``). Block tables hold page ids in ``[0, P)``;
+    :func:`decode_step_paged` offsets them by ``l·P`` for layer ``l``, so
+    each layer keeps its own copy of any null/sentinel page the caller
+    reserves inside ``n_pages``. The last axis is a multiple of 128 lanes
+    for the served widths, so the TPU keeps the arena row-major, the layout
+    the Pallas kernel reads, and the step updates it in place. The pool
+    carries no per-slot structure — block tables impose it per step.
     """
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads,
-             cfg.resolved_head_dim)
+    shape = (cfg.n_layers * n_pages, page_size,
+             cfg.n_kv_heads * cfg.resolved_head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -304,7 +312,8 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
 
     tokens (B,) int32; lengths (B,) int32 — positions already resident per
     slot (the new entry lands at ``lengths[b]``); tables (B, NP) int32 page
-    ids; pool_k/pool_v (L, P, ps, Kh, Dh). ``append_mask`` (B,) bool gates
+    ids in ``[0, P)``; pool_k/pool_v the flat arena (L·P, ps, Kh·Dh) of
+    :func:`paged_pool_init`. ``append_mask`` (B,) bool gates
     the KV append per slot (False = the lane is idle/stalled and rides the
     batch; its logits are garbage and must be ignored). Returns
     ``(logits (B, V), pool_k', pool_v')`` — pools should be donated.
@@ -320,7 +329,11 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
     Every per-slot quantity (rope position, KV length, page chain) is a
     batched vector, so one launch serves ragged slots; the attention itself
     is the fused paged kernel (``repro.kernels.paged_attention``), reading
-    K/V in place from the pool through the block table. ``impl`` (None)
+    K/V in place from the pool through the block table. The layer scan
+    carries the whole arena and hands layer ``l`` the tables offset by
+    ``l·P``: the append scatters into the carried arena in place and the
+    kernel reads it there, so no layer's pool is sliced out, copied or
+    stacked back. ``impl`` (None)
     follows the platform: the compiled Pallas kernel on a TPU, the
     bit-exact ``ref`` gather elsewhere; pass it only to pit one against
     the other.
@@ -344,52 +357,42 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
         impl = "pallas" if backend.on_tpu() else "ref"
     positions = lengths[:, None]
     x = _embed(params, cfg, tokens[:, None], None, positions)
-    il = cfg.moe_interleave if cfg.moe_experts >= 2 else 1
-    n_super = cfg.n_layers // il
+    # supports_paged excludes MoE, so every superblock is one dense layer
+    rows = pool_k.shape[0] // cfg.n_layers      # P: one layer's pages
 
-    def block(carry, blk_and_pool):
-        x, aux = carry
-        blk, pk_b, pv_b = blk_and_pool
-        pk_o, pv_o = [], []
-        for j in range(il):
-            lp = blk[f"l{j}"]
-            ap = jax.tree.map(lambda a: a.astype(x.dtype), lp["attn"])
-            with jax.named_scope("attn.qkv"):
-                h = L.rmsnorm(x, ap["ln"], cfg.norm_eps)
-                q, k, v = _project_qkv(h, ap, cfg, positions)
-            # the append and the attention carry the scopes attn.append and
-            # attn.kernel
-            o, pk_j, pv_j = paged_ops.paged_decode_append(
-                q[:, 0], k[:, 0], v[:, 0], pk_b[j], pv_b[j], tables, lengths,
-                append_mask=append_mask, window=window, impl=impl)
-            with jax.named_scope("attn.out"):
-                if tp_axis is not None:
-                    # (B, H/tp, Dh) per device -> (B, H, Dh), heads in mesh
-                    # order = single-device order; wo is replicated, so the
-                    # projection below is bitwise the unsharded one
-                    o = lax.all_gather(o, tp_axis, axis=1, tiled=True)
-                x = x + jnp.einsum("bshk,hkd->bsd", o[:, None],
-                                   ap["wo"].astype(o.dtype))
-            with jax.named_scope("mlp"):
-                x, a = _ffn(x, lp, cfg, _is_moe_layer(cfg, j))
-            aux = aux + a
-            pk_o.append(pk_j)
-            pv_o.append(pv_j)
-        return (x, aux), (jnp.stack(pk_o), jnp.stack(pv_o))
+    def block(carry, blk_and_layer):
+        x, pk, pv = carry
+        blk, layer = blk_and_layer
+        lp = blk["l0"]
+        ap = jax.tree.map(lambda a: a.astype(x.dtype), lp["attn"])
+        with jax.named_scope("attn.qkv"):
+            h = L.rmsnorm(x, ap["ln"], cfg.norm_eps)
+            q, k, v = _project_qkv(h, ap, cfg, positions)
+        # the append and the attention carry the scopes attn.append and
+        # attn.kernel
+        o, pk, pv = paged_ops.paged_decode_append(
+            q[:, 0], k[:, 0], v[:, 0], pk, pv, tables + layer * rows,
+            lengths, append_mask=append_mask, window=window, impl=impl)
+        with jax.named_scope("attn.out"):
+            if tp_axis is not None:
+                # (B, H/tp, Dh) per device -> (B, H, Dh), heads in mesh
+                # order = single-device order; wo is replicated, so the
+                # projection below is bitwise the unsharded one
+                o = lax.all_gather(o, tp_axis, axis=1, tiled=True)
+            x = x + jnp.einsum("bshk,hkd->bsd", o[:, None],
+                               ap["wo"].astype(o.dtype))
+        with jax.named_scope("mlp"):
+            x, _ = _ffn(x, lp, cfg, False)
+        return (x, pk, pv), None
 
-    # the scan's slicing of the pools per layer, and its stacking of the
-    # updated pools, land under "layers"
     with jax.named_scope("layers"):
-        pk = pool_k.reshape(n_super, il, *pool_k.shape[1:])
-        pv = pool_v.reshape(n_super, il, *pool_v.shape[1:])
-        (x, _aux), (pk_new, pv_new) = lax.scan(
-            block, (x, jnp.zeros((), jnp.float32)), (params["blocks"], pk, pv))
-        pk_new = pk_new.reshape(pool_k.shape)
-        pv_new = pv_new.reshape(pool_v.shape)
+        (x, pool_k, pool_v), _ = lax.scan(
+            block, (x, pool_k, pool_v),
+            (params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     with jax.named_scope("head"):
         x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
         logits = _head(params, cfg, x)[:, 0]
-    return logits, pk_new, pv_new
+    return logits, pool_k, pool_v
 
 
 def decode_step(params, cfg: ModelConfig, cache: KVCache, tokens):

@@ -964,9 +964,12 @@ class ContinuousBatchingEngine:
                 else self._zero_prev)
         fb = jnp.asarray(feedback)
         em = jnp.asarray(emit)
-        temp = jnp.asarray(self._temp)
-        tk = jnp.asarray(self._topk)
-        tp = jnp.asarray(self._topp)
+        # copies: on the CPU a device array may alias the numpy buffer it
+        # came from, and admission rewrites these rows while the launch can
+        # still be in flight (async dispatch)
+        temp = jnp.asarray(self._temp.copy())
+        tk = jnp.asarray(self._topk.copy())
+        tp = jnp.asarray(self._topp.copy())
         if self.paged:
             arena = self._arena
             tables, lengths = tables

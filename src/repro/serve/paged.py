@@ -103,8 +103,12 @@ def pool_signature(cfg: ModelConfig) -> tuple:
 @dataclasses.dataclass
 class PoolArena:
     """Device storage for one cache signature: a (k, v) pair shaped
-    ``(L, n_pages + 1, page_size, Kh, Dh)`` — the extra row is the null
-    page. Engines mutate ``k``/``v`` in place per step (donated buffers);
+    ``(L·(n_pages + 1), page_size, Kh·Dh)`` — one flat page space in which
+    layer ``l``'s page ``p`` is row ``l·(n_pages + 1) + p``; each layer's
+    extra row is its null page
+    (:func:`repro.models.transformer.paged_pool_init`). Page ids stay in
+    ``[0, n_pages]``; the step offsets them per layer. Engines mutate
+    ``k``/``v`` in place per step (donated buffers);
     same-signature engines share one arena, so page contents written by one
     engine are readable by every other through the shared id space."""
 
@@ -150,11 +154,11 @@ class PagePool:
         use). Same-signature configs on the same mesh get the *same*
         arena object.
 
-        With ``mesh`` the arena's KV-head axis is sharded over
+        With ``mesh`` the arena's head-major lane axis is sharded over
         ``tp_axis`` (:func:`repro.sharding.rules.serve_pool_spec`): each
-        device holds ``Kh/tp`` heads of every page — the arena is
-        *split*, not duplicated, so ``tp`` devices cost the same KV bytes
-        as one. Page ids (and the host allocator) are mesh-invariant;
+        device holds the lanes of ``Kh/tp`` heads of every page — the
+        arena is *split*, not duplicated, so ``tp`` devices cost the same
+        KV bytes as one. Page ids (and the host allocator) are mesh-invariant;
         arenas on different meshes are distinct device storage keyed
         ``(signature, mesh)``, because a page's bytes physically live
         only on the mesh slice that wrote them — replicas on disjoint

@@ -242,10 +242,13 @@ def serve_param_specs(cfg, tp_axis: str = "model"):
 
 
 def serve_pool_spec(tp_axis: str = "model") -> PartitionSpec:
-    """PartitionSpec of a KV pool arena ``(L, P, page, Kh, Dh)``: sharded
-    over the KV-head axis only — page ids (and the block tables indexing
-    them) stay device-invariant, so host-side allocation is unchanged."""
-    return PartitionSpec(None, None, None, tp_axis)
+    """PartitionSpec of a KV pool arena ``(L·P, page, Kh·Dh)``: sharded over
+    the lane axis only. Its lanes are head-major (KV head ``kh`` at
+    ``[kh·Dh, (kh+1)·Dh)``), so each device holds its ``Kh/tp`` heads as
+    one contiguous block of lanes. Page rows — and the block tables
+    indexing them — stay device-invariant, so host-side allocation is
+    unchanged."""
+    return PartitionSpec(None, None, tp_axis)
 
 
 def shard_bytes(shape: Sequence[int], spec: PartitionSpec, mesh: Mesh,

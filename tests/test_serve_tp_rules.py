@@ -147,8 +147,8 @@ def test_serve_param_spec_head_dim_suffix_rule():
 
 
 def test_pool_spec_and_shard_bytes():
-    """The arena spec shards only the KV-head dim; per-device bytes come
-    out to 1/tp of the footprint for any divisible head count."""
+    """The arena spec shards only the head-major lane dim; per-device bytes
+    come out to 1/tp of the footprint for any divisible head count."""
 
     class _M:
         axis_names = ("model",)
@@ -157,9 +157,10 @@ def test_pool_spec_and_shard_bytes():
             shape = (2,)
 
     spec = R.serve_pool_spec()
-    assert spec == P(None, None, None, "model")
-    full = R.shard_bytes((4, 8, 8, 2, 16), P(), _M, 4)
-    half = R.shard_bytes((4, 8, 8, 2, 16), spec, _M, 4)
+    assert spec == P(None, None, "model")
+    # (L·P, page, Kh·Dh): 4 layers of 8 pages, 2 KV heads of 16 lanes
+    full = R.shard_bytes((4 * 8, 8, 2 * 16), P(), _M, 4)
+    half = R.shard_bytes((4 * 8, 8, 2 * 16), spec, _M, 4)
     assert full == 4 * 8 * 8 * 2 * 16 * 4
-    # the leading (L, P, page) dims never split: pages stay device-invariant
+    # the leading (L·P, page) dims never split: pages stay device-invariant
     assert half * 2 == full
