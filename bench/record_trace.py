@@ -2,9 +2,9 @@
 
   python3 -m bench.record_trace tests/benchmark/data
 
-On a TPU: the granite-3-2b configuration cut by ``small_config`` serves
-four requests through the engine; three engine steps are traced with the
-harness's own spans, and the trace is written to
+On a TPU: the granite-3-2b configuration, cut by its model file's
+``small``, serves four requests through the engine; three engine steps
+are traced with the harness's own spans, and the trace is written to
 ``<dir>/engine_steps.xplane.pb``.
 """
 
@@ -16,21 +16,18 @@ import sys
 import tempfile
 import time
 
+from bench import spec
+
 
 def small_config(cfg: dict) -> dict:
-    """``cfg`` cut to 4 layers of width 128 and 4 slots of 128 tokens: a
-    size the CPU tests and the recorded trace share."""
-    return dict(cfg, num_hidden_layers=4, hidden_size=128,
-                num_attention_heads=4, num_key_value_heads=2,
-                intermediate_size=256, vocab_size=512,
-                attention_multiplier=32 ** -0.5,
-                engine={"slots": 4, "max_len": 128, "page_size": 16})
+    """``cfg`` cut to the size the CPU tests and the recorded trace share."""
+    return spec.model(cfg).small(cfg)
 
 
 def main(out_dir: str) -> int:
     import jax
 
-    from bench import harness, spec, sut, weights
+    from bench import harness, sut, weights
     from bench import trace as trace_mod
     from bench.run import ROOT, open_chip
 
@@ -41,7 +38,8 @@ def main(out_dir: str) -> int:
     rng = spec.rng(0, "record")
     harness.warm_up(engine, cfg, sut, rng)
     for i in range(4):
-        engine.submit(sut.Request(f"r{i}", rng.integers(0, 512, 20).tolist(), 8))
+        prompt = rng.integers(0, cfg["vocab_size"], 20).tolist()
+        engine.submit(sut.Request(f"r{i}", prompt, 8))
     stamper = harness._Stamper(engine, {})
     stamper.step()
     log_dir = tempfile.mkdtemp(prefix="bench-record-")

@@ -5,17 +5,23 @@ lists its metrics. Everything that belongs to one configuration, mix,
 limit or metric sits in a file of its own, found by that name:
 
 * ``bench/configs/<config>.json`` — the model as it is served;
+* ``bench/models/<equations>.py`` — the equations its ``equations`` key
+  names: the program's ``ModelConfig``, the weight tree, the float32
+  reference, the closed-form FLOPs, the layers that hold KV, and the
+  size the CPU tests cut it to;
 * ``bench/traffic/<traffic>.json`` — the parameters of one mix;
 * ``bench/limits/<cell>.json`` — the limit that decides ``correct``;
 * ``bench/metrics/<metric>.py`` — the reader of one metric. A metric
   named ``base.variant`` may share ``bench/metrics/<base>.py``.
 
-So a new cell, mix or metric is new files and new entries, never an edit.
+So a new cell, mix, metric or architecture is new files and new entries,
+never an edit.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import importlib.util
 import json
@@ -28,10 +34,6 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 def head_dim(cfg: dict) -> int:
     return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
-def norm_eps(cfg: dict) -> float:
-    return cfg.get("rms_norm_eps", cfg.get("layer_norm_eps"))
 
 
 def seed32(seed: int, stream: str) -> int:
@@ -87,13 +89,15 @@ def cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     if name not in entries:
         raise KeyError(f"no workload {name!r}; known: {sorted(entries)}")
     w = entries[name]
+    config = load_json(root / "bench" / "configs" / f"{w['config']}.json")
+    model(config, root)      # unknown equations fail here, before a run
     e2e = tuple(m for m in map(_metric, bench["end_to_end"])
                 if m.applies(name))
     per_layer = tuple(m for m in map(_metric, bench["per_layer"])
                       if m.applies(name))
     return Cell(
         name=name,
-        config=load_json(root / "bench" / "configs" / f"{w['config']}.json"),
+        config=config,
         traffic=load_json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
         chips=int(w["chips"]),
         limits=load_json(root / "bench" / "limits" / f"{name}.json"),
@@ -113,3 +117,23 @@ def reader(metric: str, root: pathlib.Path = ROOT):
             return lambda run: mod.read(run, variant)
     raise FileNotFoundError(f"no reader for metric {metric!r} under "
                             f"{root / 'bench' / 'metrics'}")
+
+
+def model(cfg: dict, root: pathlib.Path | None = None):
+    """The model file of ``cfg["equations"]``,
+    ``<root>/bench/models/<equations>.py`` (``root`` defaults to the
+    checkout)."""
+    path = (root or ROOT) / "bench" / "models" / f"{cfg['equations']}.py"
+    if not path.exists():
+        raise FileNotFoundError(f"no model file {path} for the equations "
+                                f"{cfg['equations']!r} of {cfg['name']!r}")
+    return _load_model(str(path))
+
+
+@functools.cache
+def _load_model(path: str):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_model_{pathlib.Path(path).stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -2,13 +2,13 @@
 
 One jitted call draws the whole tree, in the served dtype, so set-up pays
 one program (read back from the persistent compile cache after the first
-run) and no leaf ever exists in float32 outside that program. The tree has
-the layout the serving engine takes: stacked layers under
-``blocks["l0"]``, projections shaped ``(in, heads, head_dim)`` and
-``(heads, head_dim, out)``.
+run) and no leaf ever exists in float32 outside that program. The tree,
+in the layout the serving engine takes, is the configuration's model file's
+(``bench/models/<equations>.py`` ``shapes``).
 
-Projections are drawn N(0, 1/fan_in) over their contracted axes, the
-embedding N(0, 0.02), and norm scales 1 + N(0, 0.1). With that scaling a
+Each leaf carries its init: an int is a fan-in, drawn N(0, 1/fan_in) over
+the contracted axes; ``"embed"`` is N(0, 0.02); ``"norm"`` is 1 + N(0, 0.1);
+``("normal", std)`` is N(0, std²); ``"zeros"`` is zero. With that scaling a
 40-layer stack stays well conditioned, so a rounding-sized difference in
 one layer stays rounding-sized at the logits (see ``PERF.md``).
 """
@@ -21,30 +21,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from bench.spec import head_dim
+from bench import spec
 
 
 def shapes(cfg: dict) -> dict:
     """``{path: (shape, fan_in or init tag)}`` for every leaf of the tree."""
-    L, d = cfg["num_hidden_layers"], cfg["hidden_size"]
-    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, f, v = head_dim(cfg), cfg["intermediate_size"], cfg["vocab_size"]
-    out = {
-        "embed": ((v, d), "embed"),
-        "blocks/l0/attn/ln": ((L, d), "norm"),
-        "blocks/l0/attn/wq": ((L, d, h, hd), d),
-        "blocks/l0/attn/wk": ((L, d, kv, hd), d),
-        "blocks/l0/attn/wv": ((L, d, kv, hd), d),
-        "blocks/l0/attn/wo": ((L, h, hd, d), h * hd),
-        "blocks/l0/ln_mlp": ((L, d), "norm"),
-        "blocks/l0/mlp/w_gate": ((L, d, f), d),
-        "blocks/l0/mlp/w_up": ((L, d, f), d),
-        "blocks/l0/mlp/w_down": ((L, f, d), f),
-        "ln_f": ((d,), "norm"),
-    }
-    if not cfg["tie_word_embeddings"]:
-        out["head"] = ((d, v), d)
-    return out
+    return spec.model(cfg).shapes(cfg)
 
 
 def _nest(flat: dict) -> dict:
@@ -64,8 +46,14 @@ def _draw(key, shape, init, dtype):
         w = z * 0.02
     elif init == "norm":
         w = 1.0 + 0.1 * z
-    else:
+    elif init == "zeros":
+        w = jnp.zeros(shape, jnp.float32)
+    elif isinstance(init, tuple) and init[0] == "normal":
+        w = z * init[1]
+    elif isinstance(init, int):
         w = z * (1.0 / math.sqrt(init))
+    else:
+        raise ValueError(f"unknown init {init!r}")
     return w.astype(dtype)
 
 

@@ -75,7 +75,9 @@ def test_trace_readers_from_a_summary():
     steps = run.window_steps(run.traced)
     run.trace = trace.TraceSummary(
         window_s=1.0, busy_s=0.75, n_devices=1,
-        op_seconds={"fusion.1": 1.0, "%closed_call.3 custom-call tpu_custom_call": 0.25},
+        op_seconds={"fusion.1": 1.0,
+                    "%paged_attention.9 custom-call tpu_custom_call": 0.25,
+                    "%closed_call.3 custom-call tpu_custom_call": 0.5},
         idle_by_span={"bench.step": 0.5})
     assert read("device_idle_share.latency", run) == pytest.approx(25.0)
     cfg = run.cfg
@@ -88,9 +90,11 @@ def test_trace_readers_from_a_summary():
     mfu = 100 * sum(flops.tokens_flops(cfg, s.fed, s.attended)
                     for s in steps) / (seconds * PEAKS["bf16_flops_per_s"])
     assert read("step_mfu.latency", run) == pytest.approx(mfu)
-    # a trace with device ops but no kernel is an error, never 0 or silence
-    run.trace = dataclasses.replace(run.trace, op_seconds={"fusion.1": 1.0})
-    with pytest.raises(LookupError, match="tpu_custom_call"):
+    # a trace with device ops but no kernel is an error, never 0 or
+    # silence, also where another Pallas kernel runs
+    run.trace = dataclasses.replace(run.trace, op_seconds={
+        "fusion.1": 1.0, "%closed_call.3 custom-call tpu_custom_call": 0.5})
+    with pytest.raises(LookupError, match="paged_attention"):
         read("paged_attn_roofline.latency", run)
 
 

@@ -24,8 +24,10 @@ def plane(name, lines):
 
 FUSION = ("%fusion.1 = bf16[4,128]{1,0:T(4,128)(2,1)} fusion(bf16[4,128]{1,0} "
           "%p.1), kind=kLoop, calls=%fused_computation.1")
-KERNEL = ("%closed_call.2 = bf16[4,4,32]{2,1,0} custom-call(s32[4,8]{1,0} %a, "
-          'bf16[97,16,2,32]{3,2,1,0} %b), custom_call_target="tpu_custom_call"')
+KERNEL = ("%paged_attention.2 = bf16[4,4,32]{2,1,0} custom-call(s32[4,8]{1,0} %a, "
+          'bf16[97,16,64]{2,1,0} %b), custom_call_target="tpu_custom_call"')
+OTHER = ("%closed_call.5 = bf16[4,64]{1,0} custom-call(bf16[4,64]{1,0} %d), "
+         'custom_call_target="tpu_custom_call"')
 COPY = "%copy.3 = bf16[4,97]{1,0:T(2,128)} copy(bf16[4,97]{0,1} %c)"
 LOOP = ("%while.6 = (s32[]{:T(128)}, bf16[4,1,128]{2,0,1:T(4,128)(2,1)}) "
         "while((s32[]{:T(128)}, bf16[4,1,128]{2,0,1}) %tuple.7), "
@@ -35,15 +37,16 @@ LOOP = ("%while.6 = (s32[]{:T(128)}, bf16[4,1,128]{2,0,1:T(4,128)(2,1)}) "
 def made_up_trace():
     """A 100 ns window; ops at [10,30) and [20,40) overlap, then [60,70)
     and [90,120) (clipped at 100), the first two inside a loop over [10,40) that
-    counts toward busy time but not toward op time. Host: a step span over [0,50), a
-    stamp span over [50,80)."""
+    counts toward busy time but not toward op time; another Pallas kernel
+    over [60,65). Host: a step span over [0,50), a stamp span over [50,80)."""
     host = plane("/host:CPU", [line("python", [
         ev("bench.window", 0, 100), ev("bench.step", 0, 50),
         ev("bench.stamp", 50, 80), ev("PjitFunction(step)", 5, 45)])])
     dev = plane("/device:TPU:0", [
         line("XLA Modules", [ev("jit_step", 10, 70)]),
         line("XLA Ops", [ev(FUSION, 10, 30), ev(KERNEL, 20, 40),
-                         ev(FUSION, 60, 70), ev(COPY, 90, 120),
+                         ev(FUSION, 60, 70), ev(OTHER, 60, 65),
+                         ev(COPY, 90, 120),
                          ev(LOOP, 10, 40)])])
     return types.SimpleNamespace(planes=[host, dev])
 
@@ -64,7 +67,10 @@ def test_busy_union_op_time_and_idle_by_span():
     b = s.breakdown()
     assert b["device_ops"][0][0] == "%fusion.1 fusion"
     assert len(b["idle_gaps"]) == 3
-    assert "%closed_call.2 custom-call tpu_custom_call" in s.op_seconds
+    assert "%paged_attention.2 custom-call tpu_custom_call" in s.op_seconds
+    # another Pallas kernel is not the paged-attention kernel
+    assert s.op_seconds["%closed_call.5 custom-call tpu_custom_call"] == (
+        pytest.approx(5e-9))
 
 
 def test_a_trace_without_the_window_span_is_refused():
