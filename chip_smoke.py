@@ -115,7 +115,8 @@ def compile_step(engine: ContinuousBatchingEngine) -> tuple[str, float]:
     n = engine.n_lanes
     tables, lengths = engine._build_tables()
     off = jnp.zeros((n,), bool)
-    args = (engine.params, engine._arena.k, engine._arena.v, tables, lengths,
+    args = (engine.params, engine._arena.k, engine._arena.v, engine._state,
+            tables, lengths,
             jnp.zeros((n,), jnp.int32), off, engine._zero_prev, off, off,
             engine._keys, jnp.asarray(engine._temp),
             jnp.asarray(engine._topk), jnp.asarray(engine._topp))
@@ -232,7 +233,7 @@ def sharded_vs_single(cfg, params, mesh, seed: int) -> dict:
     call = paged._decode_call(cfg, None, mesh, "model")
     sharded = jax.jit(lambda *a: call(*a)[0])(
         paged.place_params(cfg, params, mesh), jax.device_put(pool_k, spec),
-        jax.device_put(pool_v, spec), tables, lengths, toks,
+        jax.device_put(pool_v, spec), None, tables, lengths, toks,
         jnp.ones((SLOTS,), bool))
     err = logit_error(sharded, single)
     check(err["rel_err"] <= LOGIT_RTOL, err)

@@ -21,6 +21,7 @@ ARCHS = (
     "llama4_maverick_400b",
     "mamba2_370m",
     "recurrentgemma_2b",
+    "granite_4_0_h_micro",
 )
 
 # accept dashed ids from the assignment table too

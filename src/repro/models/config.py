@@ -9,7 +9,8 @@ from typing import Literal
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: Literal["dense", "moe", "ssm", "hybrid", "audio", "vlm"]
+    family: Literal["dense", "moe", "ssm", "hybrid", "ssm_hybrid", "audio",
+                    "vlm"]
     n_layers: int
     d_model: int
     vocab: int
@@ -40,6 +41,17 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_chunk: int = 256
     ssm_compute_dtype: str = "float32"   # bfloat16: halve SSD scan traffic
+    # ssm_hybrid (granite-4.0-h / Nemotron-H): the kind of each layer,
+    # "mamba" (a Mamba-2 mixer) or "attention" (GQA, no positions), in a
+    # pattern that repeats; every layer is followed by the MLP
+    layer_types: tuple[str, ...] = ()
+    # granite's scalings: embeddings times embedding_multiplier, attention
+    # scores times attention_multiplier (None: 1/sqrt(head_dim)), each
+    # residual branch times residual_multiplier, logits over logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # hybrid (recurrentgemma / griffin)
     rnn_width: int | None = None
     attn_window: int | None = None         # local-attention window in hybrid
@@ -59,9 +71,15 @@ class ModelConfig:
     kv_block: int = 512
 
     def __post_init__(self):
-        if self.family in ("dense", "moe", "audio", "vlm", "hybrid"):
+        if self.family in ("dense", "moe", "audio", "vlm", "hybrid",
+                           "ssm_hybrid"):
             if self.n_heads and self.n_heads % max(self.n_kv_heads, 1):
                 raise ValueError("n_heads must be a multiple of n_kv_heads")
+        if self.family == "ssm_hybrid" and (
+                len(self.layer_types) != self.n_layers
+                or not set(self.layer_types) <= {"mamba", "attention"}):
+            raise ValueError("ssm_hybrid needs one of 'mamba' or 'attention' "
+                             "in layer_types for each layer")
         if self.family == "moe" and self.moe_experts < 2:
             raise ValueError("moe family needs moe_experts >= 2")
 

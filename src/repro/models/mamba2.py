@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.ssm_decode.ref import ssm_step
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.sharding import axes as lx
@@ -141,6 +142,9 @@ def ssd(x, dA, B, C, *, impl: str, chunk: int, init_state=None,
 
 
 def _layer_decls(cfg: ModelConfig) -> dict[str, Any]:
+    """One Mamba-2 block: its pre-norm ``ln`` and the mixer's weights. The
+    published in_proj is split into its five outputs (z, x, B, C, dt) and
+    the conv over xBC into its three channel groups, each with its bias."""
     d, di, h, n, w = (cfg.d_model, cfg.d_inner, cfg.ssm_heads, cfg.ssm_state,
                       cfg.ssm_conv_width)
     return {
@@ -151,8 +155,11 @@ def _layer_decls(cfg: ModelConfig) -> dict[str, Any]:
         "w_C": ParamDecl((d, n), Axes(lx.EMBED, lx.STATE), init="fan_in"),
         "w_dt": ParamDecl((d, h), Axes(lx.EMBED, lx.HEADS), init="fan_in"),
         "conv_x": L.conv1d_decl(w, di),
+        "conv_x_b": ParamDecl((di,), Axes(lx.RNN_WIDTH), init="zeros"),
         "conv_B": ParamDecl((w, n), Axes(lx.CONV, lx.STATE), init="fan_in"),
+        "conv_B_b": ParamDecl((n,), Axes(lx.STATE), init="zeros"),
         "conv_C": ParamDecl((w, n), Axes(lx.CONV, lx.STATE), init="fan_in"),
+        "conv_C_b": ParamDecl((n,), Axes(lx.STATE), init="zeros"),
         "A_log": ParamDecl((h,), Axes(lx.HEADS), init="zeros"),
         "D": ParamDecl((h,), Axes(lx.HEADS), init="ones"),
         "dt_bias": ParamDecl((h,), Axes(lx.HEADS), init="zeros"),
@@ -177,32 +184,35 @@ def decls(cfg: ModelConfig) -> dict[str, Any]:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class SSMCache:
-    conv_x: jax.Array   # (L, B, W-1, d_inner)
-    conv_B: jax.Array   # (L, B, W-1, n)
-    conv_C: jax.Array   # (L, B, W-1, n)
+    conv_x: jax.Array   # (L, W-1, B, d_inner) last conv inputs, oldest first
+    conv_B: jax.Array   # (L, W-1, B, n)
+    conv_C: jax.Array   # (L, W-1, B, n)
     state: jax.Array    # (L, B, H, P, N)
     pos: jax.Array
 
     @staticmethod
-    def _shapes(cfg: ModelConfig, batch: int):
+    def _shapes(cfg: ModelConfig, batch: int, layers: int | None = None):
         w = cfg.ssm_conv_width
+        nl = cfg.n_layers if layers is None else layers
         return (
-            (cfg.n_layers, batch, w - 1, cfg.d_inner),
-            (cfg.n_layers, batch, w - 1, cfg.ssm_state),
-            (cfg.n_layers, batch, w - 1, cfg.ssm_state),
-            (cfg.n_layers, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+            (nl, w - 1, batch, cfg.d_inner),
+            (nl, w - 1, batch, cfg.ssm_state),
+            (nl, w - 1, batch, cfg.ssm_state),
+            (nl, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
         )
 
     @staticmethod
-    def init(cfg, batch, max_len=None, dtype=jnp.bfloat16) -> "SSMCache":
-        s = SSMCache._shapes(cfg, batch)
+    def init(cfg, batch, max_len=None, dtype=jnp.bfloat16,
+             layers: int | None = None) -> "SSMCache":
+        s = SSMCache._shapes(cfg, batch, layers)
         return SSMCache(jnp.zeros(s[0], dtype), jnp.zeros(s[1], dtype),
                         jnp.zeros(s[2], dtype), jnp.zeros(s[3], jnp.float32),
                         jnp.zeros((), jnp.int32))
 
     @staticmethod
-    def abstract(cfg, batch, max_len=None, dtype=jnp.bfloat16) -> "SSMCache":
-        s = SSMCache._shapes(cfg, batch)
+    def abstract(cfg, batch, max_len=None, dtype=jnp.bfloat16,
+                 layers: int | None = None) -> "SSMCache":
+        s = SSMCache._shapes(cfg, batch, layers)
         return SSMCache(jax.ShapeDtypeStruct(s[0], dtype),
                         jax.ShapeDtypeStruct(s[1], dtype),
                         jax.ShapeDtypeStruct(s[2], dtype),
@@ -212,57 +222,110 @@ class SSMCache:
     @staticmethod
     def axes() -> "SSMCache":
         return SSMCache(
-            Axes(lx.LAYERS, lx.DECODE_BATCH, None, lx.RNN_WIDTH),
-            Axes(lx.LAYERS, lx.DECODE_BATCH, None, lx.STATE),
-            Axes(lx.LAYERS, lx.DECODE_BATCH, None, lx.STATE),
+            Axes(lx.LAYERS, None, lx.DECODE_BATCH, lx.RNN_WIDTH),
+            Axes(lx.LAYERS, None, lx.DECODE_BATCH, lx.STATE),
+            Axes(lx.LAYERS, None, lx.DECODE_BATCH, lx.STATE),
             Axes(lx.LAYERS, lx.DECODE_BATCH, lx.HEADS, lx.HEAD_DIM, lx.STATE),
             Axes(),
         )
 
 
-def _mix(x, lp, cfg: ModelConfig):
-    """Shared projection stage. Returns z, xs (pre-scaled), dA, B, C, dt."""
-    h = L.rmsnorm(x, lp["ln"], cfg.norm_eps)
-    z = h @ lp["w_z"].astype(h.dtype)
-    xin = h @ lp["w_x"].astype(h.dtype)
-    Braw = h @ lp["w_B"].astype(h.dtype)
-    Craw = h @ lp["w_C"].astype(h.dtype)
-    dt_raw = h @ lp["w_dt"].astype(h.dtype)
-    return z, xin, Braw, Craw, dt_raw
+# ---------------------------------------------------------------------------
+# The mixer (shared with the Mamba-2 layers of models/hybrid.py)
+# ---------------------------------------------------------------------------
 
 
-def _ssm_math(xin, Braw, Craw, dt_raw, lp, cfg: ModelConfig):
-    b, s = xin.shape[:2]
+def _project(h, lp):
+    """in_proj of the normed input: z, x, B, C, dt (before the conv)."""
+    return tuple(h @ lp[k].astype(h.dtype)
+                 for k in ("w_z", "w_x", "w_B", "w_C", "w_dt"))
+
+
+def _conv(v, lp, group: str):
+    """Causal depthwise conv of one channel group over a sequence (B, S, C)
+    with its bias, **then** SiLU. Returns (activations, the last W-1 conv
+    inputs as (W-1, B, C))."""
+    y, tail = L.causal_conv1d(v, lp[f"conv_{group}"].astype(v.dtype))
+    return (jax.nn.silu(y + lp[f"conv_{group}_b"].astype(y.dtype)),
+            jnp.swapaxes(tail, 0, 1))
+
+
+def _conv_step(v, lp, group: str, tail):
+    """:func:`_conv` for one token ``v`` (B, C) after the inputs ``tail``
+    (W-1, B, C), summed in the same order. Returns (activations (B, C),
+    the new tail)."""
+    w = lp[f"conv_{group}"].astype(v.dtype)
+    xx = jnp.concatenate([tail, v[None]], axis=0)
+    y = sum(xx[i] * w[i] for i in range(w.shape[0]))
+    return (jax.nn.silu(y + lp[f"conv_{group}_b"].astype(y.dtype)),
+            xx[1:].astype(tail.dtype))
+
+
+def _dt_A(dt_raw, lp):
+    dt = jax.nn.softplus(dt_raw.astype(F32) + lp["dt_bias"].astype(F32))
+    return dt, -jnp.exp(lp["A_log"].astype(F32))
+
+
+def _gate_out(y, z, lp, cfg: ModelConfig, dtype):
+    """Gated RMSNorm of ``y·silu(z)`` over d_inner, then out_proj."""
+    g = L.rmsnorm(y * jax.nn.silu(z.astype(F32)), lp["ln_gate"], cfg.norm_eps)
+    g = g.astype(dtype)
+    return g @ lp["w_out"].astype(dtype)
+
+
+def mix_seq(h, lp, cfg: ModelConfig):
+    """The mixer over a whole sequence ``h`` (B, S, D), already normed.
+    Returns ``(out (B, S, D), conv tails (x, B, C), final state)``."""
+    b, s = h.shape[:2]
     hn, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-    dt = jax.nn.softplus(dt_raw.astype(F32) + lp["dt_bias"].astype(F32))  # (b,s,h)
-    A = -jnp.exp(lp["A_log"].astype(F32))                                  # (h,)
-    dA = dt * A
-    xh = xin.reshape(b, s, hn, p)
-    xs = xh.astype(F32) * dt[..., None]
-    Bh = jnp.broadcast_to(Braw[:, :, None, :], (b, s, hn, n))
-    Ch = jnp.broadcast_to(Craw[:, :, None, :], (b, s, hn, n))
-    return xh, xs, dA, Bh, Ch
+    z, xr, Br, Cr, dt_raw = _project(h, lp)
+    x, tx = _conv(xr, lp, "x")
+    Bm, tB = _conv(Br, lp, "B")
+    Cm, tC = _conv(Cr, lp, "C")
+    dt, A = _dt_A(dt_raw, lp)
+    xh = x.reshape(b, s, hn, p)
+    y, state = ssd(xh.astype(F32) * dt[..., None], dt * A,
+                   jnp.broadcast_to(Bm[:, :, None, :], (b, s, hn, n)),
+                   jnp.broadcast_to(Cm[:, :, None, :], (b, s, hn, n)),
+                   impl=cfg.scan_impl, chunk=cfg.ssm_chunk,
+                   compute_dtype=jnp.dtype(cfg.ssm_compute_dtype))
+    y = y.astype(F32) + xh.astype(F32) * lp["D"].astype(F32)[:, None]
+    out = _gate_out(y.reshape(b, s, cfg.d_inner), z, lp, cfg, h.dtype)
+    return out, (tx, tB, tC), state
 
 
-def _block_train(x, lp, cfg: ModelConfig):
-    z, xin, Braw, Craw, dt_raw = _mix(x, lp, cfg)
-    xin, _ = L.causal_conv1d(jax.nn.silu(xin), lp["conv_x"].astype(xin.dtype))
-    Braw, _ = L.causal_conv1d(jax.nn.silu(Braw), lp["conv_B"].astype(Braw.dtype))
-    Craw, _ = L.causal_conv1d(jax.nn.silu(Craw), lp["conv_C"].astype(Craw.dtype))
-    xh, xs, dA, Bh, Ch = _ssm_math(xin, Braw, Craw, dt_raw, lp, cfg)
-    y, _ = ssd(xs, dA, Bh, Ch, impl=cfg.scan_impl, chunk=cfg.ssm_chunk,
-               compute_dtype=jnp.dtype(cfg.ssm_compute_dtype))
-    y = y + xh.astype(F32) * lp["D"].astype(F32)[None, None, :, None]
-    y = y.reshape(*x.shape[:2], cfg.d_inner)
-    y = L.rmsnorm(y.astype(x.dtype) * jax.nn.silu(z), lp["ln_gate"], cfg.norm_eps)
-    return x + y @ lp["w_out"].astype(y.dtype)
+def mix_step(h, lp, cfg: ModelConfig, tails, recur):
+    """The mixer on one token ``h`` (B, 1, D), already normed, against the
+    conv tails ``(x, B, C)``, each (W-1, B, channels). ``recur(x, dt, A, B, C, D)`` runs
+    the state-space recurrence (``kernels/ssm_decode``) on the state it
+    holds and returns ``(y (B, H, P) f32, its new state)``. Returns
+    ``(out (B, 1, D), new tails, recur's new state)``."""
+    b = h.shape[0]
+    hn, p = cfg.ssm_heads, cfg.ssm_head_dim
+    with jax.named_scope("ssm.in_proj"):
+        z, xr, Br, Cr, dt_raw = _project(h, lp)
+    with jax.named_scope("ssm.conv"):
+        x, tx = _conv_step(xr[:, 0], lp, "x", tails[0])
+        Bm, tB = _conv_step(Br[:, 0], lp, "B", tails[1])
+        Cm, tC = _conv_step(Cr[:, 0], lp, "C", tails[2])
+        dt, A = _dt_A(dt_raw[:, 0], lp)
+    y, state = recur(x.reshape(b, hn, p), dt, A, Bm, Cm, lp["D"].astype(F32))
+    with jax.named_scope("ssm.out"):
+        out = _gate_out(y.reshape(b, 1, cfg.d_inner), z, lp, cfg, h.dtype)
+    return out, (tx, tB, tC), state
+
+
+# ---------------------------------------------------------------------------
+# Model
+# ---------------------------------------------------------------------------
 
 
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None, positions=None):
     x = params["embed"].astype(jnp.bfloat16)[tokens] if embeds is None else embeds
 
     def body(carry, lp):
-        return _block_train(carry, jax.tree.map(lambda a: a, lp), cfg), None
+        out, _, _ = mix_seq(L.rmsnorm(carry, lp["ln"], cfg.norm_eps), lp, cfg)
+        return carry + out, None
 
     from repro.models.transformer import _maybe_remat
 
@@ -274,23 +337,12 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None, positions=None):
 def prefill(params, cfg: ModelConfig, tokens=None, embeds=None, max_len=None):
     """Prompt pass producing the SSM cache (final conv tails + states)."""
     x = params["embed"].astype(jnp.bfloat16)[tokens] if embeds is None else embeds
-    b, s = x.shape[:2]
-    w = cfg.ssm_conv_width
+    s = x.shape[1]
 
     def body(carry, lp):
-        xc = carry
-        z, xin, Braw, Craw, dt_raw = _mix(xc, lp, cfg)
-        xin_a, Braw_a, Craw_a = (jax.nn.silu(xin), jax.nn.silu(Braw), jax.nn.silu(Craw))
-        conv_tails = (xin_a[:, -(w - 1):], Braw_a[:, -(w - 1):], Craw_a[:, -(w - 1):])
-        xin_c, _ = L.causal_conv1d(xin_a, lp["conv_x"].astype(xin.dtype))
-        Braw_c, _ = L.causal_conv1d(Braw_a, lp["conv_B"].astype(Braw.dtype))
-        Craw_c, _ = L.causal_conv1d(Craw_a, lp["conv_C"].astype(Craw.dtype))
-        xh, xs, dA, Bh, Ch = _ssm_math(xin_c, Braw_c, Craw_c, dt_raw, lp, cfg)
-        y, st = ssd(xs, dA, Bh, Ch, impl=cfg.scan_impl, chunk=cfg.ssm_chunk)
-        y = y + xh.astype(F32) * lp["D"].astype(F32)[None, None, :, None]
-        y = y.reshape(b, s, cfg.d_inner)
-        y = L.rmsnorm(y.astype(xc.dtype) * jax.nn.silu(z), lp["ln_gate"], cfg.norm_eps)
-        return xc + y @ lp["w_out"].astype(y.dtype), (conv_tails, st)
+        out, tails, st = mix_seq(L.rmsnorm(carry, lp["ln"], cfg.norm_eps),
+                                 lp, cfg)
+        return carry + out, (tails, st)
 
     body_fn = body if cfg.remat == "none" else jax.checkpoint(body)
     x, (tails, states) = lax.scan(body_fn, x, params["blocks"])
@@ -307,22 +359,11 @@ def decode_step(params, cfg: ModelConfig, cache: SSMCache, tokens):
     x = params["embed"].astype(jnp.bfloat16)[tokens]
 
     def body(carry, inp):
-        xc = carry
         lp, cx, cB, cC, st = inp
-        z, xin, Braw, Craw, dt_raw = _mix(xc, lp, cfg)
-        xin_c, cx2 = L.causal_conv1d(jax.nn.silu(xin), lp["conv_x"].astype(xin.dtype), cx)
-        Braw_c, cB2 = L.causal_conv1d(jax.nn.silu(Braw), lp["conv_B"].astype(Braw.dtype), cB)
-        Craw_c, cC2 = L.causal_conv1d(jax.nn.silu(Craw), lp["conv_C"].astype(Craw.dtype), cC)
-        xh, xs, dA, Bh, Ch = _ssm_math(xin_c, Braw_c, Craw_c, dt_raw, lp, cfg)
-        # single-step recurrence
-        x_t, dA_t, B_t, C_t = xs[:, 0], dA[:, 0], Bh[:, 0], Ch[:, 0]
-        st2 = st * jnp.exp(dA_t)[..., None, None] \
-            + jnp.einsum("bhp,bhn->bhpn", x_t, B_t)
-        y = jnp.einsum("bhpn,bhn->bhp", st2, C_t)[:, None]
-        y = y + xh.astype(F32) * lp["D"].astype(F32)[None, None, :, None]
-        y = y.reshape(xc.shape[0], 1, cfg.d_inner)
-        y = L.rmsnorm(y.astype(xc.dtype) * jax.nn.silu(z), lp["ln_gate"], cfg.norm_eps)
-        return xc + y @ lp["w_out"].astype(y.dtype), (cx2, cB2, cC2, st2)
+        out, (cx, cB, cC), st = mix_step(
+            L.rmsnorm(carry, lp["ln"], cfg.norm_eps), lp, cfg, (cx, cB, cC),
+            lambda *a: ssm_step(st, *a))
+        return carry + out, (cx, cB, cC, st)
 
     x, (cx, cB, cC, st) = lax.scan(
         body, x, (params["blocks"], cache.conv_x, cache.conv_B, cache.conv_C,

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.sharding import axes as lx
@@ -85,16 +86,23 @@ class KVCache:
         return min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
 
     @staticmethod
-    def init(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16) -> "KVCache":
-        s = KVCache.cache_len(cfg, max_len)
-        shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.resolved_head_dim)
+    def _shape(cfg: ModelConfig, batch: int, max_len: int,
+               layers: int | None):
+        return (cfg.n_layers if layers is None else layers, batch,
+                KVCache.cache_len(cfg, max_len), cfg.n_kv_heads,
+                cfg.resolved_head_dim)
+
+    @staticmethod
+    def init(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
+             layers: int | None = None) -> "KVCache":
+        shape = KVCache._shape(cfg, batch, max_len, layers)
         return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                        jnp.zeros((), jnp.int32))
 
     @staticmethod
-    def abstract(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16) -> "KVCache":
-        s = KVCache.cache_len(cfg, max_len)
-        shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.resolved_head_dim)
+    def abstract(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
+                 layers: int | None = None) -> "KVCache":
+        shape = KVCache._shape(cfg, batch, max_len, layers)
         return KVCache(jax.ShapeDtypeStruct(shape, dtype),
                        jax.ShapeDtypeStruct(shape, dtype),
                        jax.ShapeDtypeStruct((), jnp.int32))
@@ -111,13 +119,29 @@ class KVCache:
 
 
 def _project_qkv(h, p, cfg: ModelConfig, positions):
+    """q, k, v of ``h``. Every attention path scales scores by
+    1/sqrt(head_dim); a config's ``attention_multiplier`` is folded into q
+    as its ratio to that scale (0.125 for granite's 1/64 at head_dim 64, a
+    power of two, so the fold is exact)."""
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"].astype(h.dtype))
     k = jnp.einsum("bsd,dhk->bshk", h, p["wk"].astype(h.dtype))
     v = jnp.einsum("bsd,dhk->bshk", h, p["wv"].astype(h.dtype))
     if cfg.pos_emb == "rope":
         q = L.rope(q, positions, cfg.rope_theta)
         k = L.rope(k, positions, cfg.rope_theta)
+    if cfg.attention_multiplier is not None:
+        q = q * jnp.asarray(cfg.attention_multiplier * q.shape[-1] ** 0.5,
+                            q.dtype)
     return q, k, v
+
+
+def _residual(x, y, cfg: ModelConfig):
+    """``x`` plus the branch output ``y``, scaled by the config's
+    ``residual_multiplier`` in float32 (granite's 0.22 is not a bf16
+    number), then rounded once."""
+    if cfg.residual_multiplier != 1.0:
+        y = (y.astype(jnp.float32) * cfg.residual_multiplier).astype(y.dtype)
+    return x + y
 
 
 def _attn_train(x, p, cfg: ModelConfig, positions):
@@ -125,7 +149,8 @@ def _attn_train(x, p, cfg: ModelConfig, positions):
     q, k, v = _project_qkv(h, p, cfg, positions)
     o = L.attention(q, k, v, impl=cfg.attn_impl, causal=True,
                     window=cfg.sliding_window)
-    return x + jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
+    return _residual(x, jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype)),
+                     cfg)
 
 
 def _attn_decode(x, p, cfg: ModelConfig, ck, cv, pos):
@@ -140,7 +165,8 @@ def _attn_decode(x, p, cfg: ModelConfig, ck, cv, pos):
     # decode always uses the chunked backend: dynamic kv_len + grouped KV
     o = L.attention(q, ck, cv, impl="chunked", causal=False, window=None,
                     kv_len=kv_len)
-    return x + jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype)), ck, cv
+    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype))
+    return _residual(x, o, cfg), ck, cv
 
 
 def _ffn(x, lp, cfg: ModelConfig, is_moe: bool):
@@ -150,9 +176,10 @@ def _ffn(x, lp, cfg: ModelConfig, is_moe: bool):
         o, aux = L.moe(h, cast, n_exp=cfg.moe_experts, top_k=cfg.moe_top_k,
                        capacity_factor=cfg.moe_capacity_factor, kind=cfg.mlp_type,
                        impl=cfg.moe_impl)
-        return x + o, aux
+        return _residual(x, o, cfg), aux
     cast = jax.tree.map(lambda a: a.astype(x.dtype), lp["mlp"])
-    return x + L.mlp(h, cast, cfg.mlp_type), jnp.zeros((), jnp.float32)
+    return (_residual(x, L.mlp(h, cast, cfg.mlp_type), cfg),
+            jnp.zeros((), jnp.float32))
 
 
 def _superblock_train(cfg: ModelConfig):
@@ -192,13 +219,18 @@ def _embed(params, cfg: ModelConfig, tokens=None, embeds=None, positions=None):
         x = params["embed"].astype(jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)[tokens]
     else:
         x = embeds
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.pos_emb == "sinusoidal":
         x = x + L.sinusoidal(positions, cfg.d_model).astype(x.dtype)
     return x
 
 
 def _head(params, cfg: ModelConfig, x):
-    return L.lm_head(x, params, cfg)
+    logits = L.lm_head(x, params, cfg)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
 
 
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None,
@@ -240,7 +272,8 @@ def prefill(params, cfg: ModelConfig, tokens=None, embeds=None, max_len: int | N
             q, k, v = _project_qkv(h, ap, cfg, positions)
             o = L.attention(q, k, v, impl=cfg.attn_impl, causal=True,
                             window=cfg.sliding_window)
-            x = x + jnp.einsum("bshk,hkd->bsd", o, ap["wo"].astype(o.dtype))
+            x = _residual(x, jnp.einsum("bshk,hkd->bsd", o,
+                                        ap["wo"].astype(o.dtype)), cfg)
             x, a = _ffn(x, lp, cfg, _is_moe_layer(cfg, j))
             aux = aux + a
             # cache tail of K/V (ring layout when windowed)
@@ -286,8 +319,10 @@ def supports_paged(cfg: ModelConfig) -> bool:
 
 
 def paged_pool_init(cfg: ModelConfig, n_pages: int, page_size: int,
-                    dtype=jnp.bfloat16):
-    """Zeroed global KV arena: a (k, v) pair of (L·P, ps, Kh·Dh).
+                    dtype=jnp.bfloat16, layers: int | None = None):
+    """Zeroed global KV arena: a (k, v) pair of (L·P, ps, Kh·Dh), where
+    ``L`` is ``layers``, the layers that hold KV (default: every layer;
+    a hybrid's attention layers only).
 
     One flat, lane-dense page space for every layer: layer ``l``'s page
     ``p`` is row ``l·P + p`` (``P = n_pages``), and a position's KV heads lie
@@ -300,9 +335,35 @@ def paged_pool_init(cfg: ModelConfig, n_pages: int, page_size: int,
     the Pallas kernel reads, and the step updates it in place. The pool
     carries no per-slot structure — block tables impose it per step.
     """
-    shape = (cfg.n_layers * n_pages, page_size,
-             cfg.n_kv_heads * cfg.resolved_head_dim)
+    shape = ((cfg.n_layers if layers is None else layers) * n_pages,
+             page_size, cfg.n_kv_heads * cfg.resolved_head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def paged_attention_layer(x, attn, cfg: ModelConfig, pool_k, pool_v, tables,
+                          lengths, *, append_mask=None, window=None,
+                          impl: str = "ref", tp_axis: str | None = None):
+    """One attention layer of a paged step, with its residual: x (B, 1, D)
+    at positions ``lengths``; ``tables`` already offset to this layer's
+    rows of the arena. Returns ``(x', pool_k', pool_v')``."""
+    ap = jax.tree.map(lambda a: a.astype(x.dtype), attn)
+    with jax.named_scope("attn.qkv"):
+        h = L.rmsnorm(x, ap["ln"], cfg.norm_eps)
+        q, k, v = _project_qkv(h, ap, cfg, lengths[:, None])
+    # the append and the attention carry the scopes attn.append and
+    # attn.kernel
+    o, pool_k, pool_v = paged_ops.paged_decode_append(
+        q[:, 0], k[:, 0], v[:, 0], pool_k, pool_v, tables, lengths,
+        append_mask=append_mask, window=window, impl=impl)
+    with jax.named_scope("attn.out"):
+        if tp_axis is not None:
+            # (B, H/tp, Dh) per device -> (B, H, Dh), heads in mesh
+            # order = single-device order; wo is replicated, so the
+            # projection below is bitwise the unsharded one
+            o = lax.all_gather(o, tp_axis, axis=1, tiled=True)
+        x = _residual(x, jnp.einsum("bshk,hkd->bsd", o[:, None],
+                                    ap["wo"].astype(o.dtype)), cfg)
+    return x, pool_k, pool_v
 
 
 def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
@@ -349,7 +410,6 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
     single-device path, bit-identical by construction.
     """
     from repro.kernels import backend
-    from repro.kernels.paged_attention import ops as paged_ops
 
     if window is None:
         window = cfg.sliding_window
@@ -364,23 +424,10 @@ def decode_step_paged(params, cfg: ModelConfig, pool_k, pool_v, tables,
         x, pk, pv = carry
         blk, layer = blk_and_layer
         lp = blk["l0"]
-        ap = jax.tree.map(lambda a: a.astype(x.dtype), lp["attn"])
-        with jax.named_scope("attn.qkv"):
-            h = L.rmsnorm(x, ap["ln"], cfg.norm_eps)
-            q, k, v = _project_qkv(h, ap, cfg, positions)
-        # the append and the attention carry the scopes attn.append and
-        # attn.kernel
-        o, pk, pv = paged_ops.paged_decode_append(
-            q[:, 0], k[:, 0], v[:, 0], pk, pv, tables + layer * rows,
-            lengths, append_mask=append_mask, window=window, impl=impl)
-        with jax.named_scope("attn.out"):
-            if tp_axis is not None:
-                # (B, H/tp, Dh) per device -> (B, H, Dh), heads in mesh
-                # order = single-device order; wo is replicated, so the
-                # projection below is bitwise the unsharded one
-                o = lax.all_gather(o, tp_axis, axis=1, tiled=True)
-            x = x + jnp.einsum("bshk,hkd->bsd", o[:, None],
-                               ap["wo"].astype(o.dtype))
+        x, pk, pv = paged_attention_layer(
+            x, lp["attn"], cfg, pk, pv, tables + layer * rows, lengths,
+            append_mask=append_mask, window=window, impl=impl,
+            tp_axis=tp_axis)
         with jax.named_scope("mlp"):
             x, _ = _ffn(x, lp, cfg, False)
         return (x, pk, pv), None

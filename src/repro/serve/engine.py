@@ -36,8 +36,12 @@ Two device backends serve the slots:
   *disowned* (pool ref + table pin released; the table's own residency
   keeps it warm for future admissions) — so a long-running windowed
   request holds O(window) device pages instead of O(seq), and prefix
-  adoption is clamped to the pages the window can still see.
-* **lanes** (SSM/hybrid/MoE configs, and engines sharing an external
+  adoption is clamped to the pages the window can still see. A Mamba-2
+  hybrid takes the same path with a KV arena over its attention layers
+  and a per-slot **state arena** beside it (conv tails and recurrent
+  state), zeroed for a slot at each admission; its prefixes are never
+  shared, since KV pages say nothing of the state at their positions.
+* **lanes** (Mamba-2/Griffin/MoE configs, and engines sharing an external
   page table *without* a shared pool) — the PR 2 layout: one full-length
   cache lane per slot (``vmap`` over batch-1 decode), snapshot pages,
   copy-on-write at the slot's first step. Pass ``paged=False`` to force
@@ -207,6 +211,7 @@ ADMIT_LINE = "serve.admit"           # raised per slot admission
 _STEP_FNS: dict = {}
 _CHUNK_FNS: dict = {}
 _RESET_FN = None
+_STATE_RESET_FN = None
 
 
 def _slot_step_fn(cfg: ModelConfig):
@@ -281,6 +286,15 @@ def _slot_reset_fn():
 
         _RESET_FN = jax.jit(reset, donate_argnums=(0,))
     return _RESET_FN
+
+
+def _state_reset_fn():
+    """Jitted zeroing of one slot's rows of the paged state arena, in
+    place (the arena is donated; the slot index is traced)."""
+    global _STATE_RESET_FN
+    if _STATE_RESET_FN is None:
+        _STATE_RESET_FN = jax.jit(registry.state_reset, donate_argnums=(0,))
+    return _STATE_RESET_FN
 
 
 # Admission-hook verdict telling the engine to drop the queue head
@@ -484,6 +498,19 @@ class ContinuousBatchingEngine:
                 "tensor parallelism is a paged-backend feature: the lane "
                 "backend has no sharded arena to decode against")
         self.paged = paged
+        # per-slot recurrent state on the paged backend (a Mamba-2
+        # hybrid): a shared prefix's KV pages say nothing of the state at
+        # their positions, so such an engine keeps no prefix table; and
+        # prompts are fed one token a step
+        stateful = paged and registry.holds_state(cfg)
+        if stateful and prefill_chunk > 1:
+            raise ValueError(
+                f"{cfg.name} holds recurrent state: prefill_chunk > 1 has no "
+                "paged path for it (prompts are fed one token a step)")
+        if stateful and page_table is not None:
+            raise ValueError(
+                f"{cfg.name} holds recurrent state: its slots cannot adopt "
+                "prefixes from a page table")
 
         # pass `page_table` to share one prefix store across engines (same
         # cfg/max_len — plus a shared `pool` to stay on the paged backend),
@@ -510,7 +537,7 @@ class ContinuousBatchingEngine:
         self._arena = None
         if self.paged:
             if self._pool is None:
-                if page_size:
+                if page_size and not stateful:
                     cap = (page_capacity if page_capacity is not None
                            else 16 * slots)
                 # a windowed engine provisions O(window) pages per slot,
@@ -520,9 +547,16 @@ class ContinuousBatchingEngine:
             if mesh is not None:
                 self.params = self._pool.placed_params(cfg, params, mesh,
                                                        tp_axis)
+        self._state = None
+        if stateful:
+            self._state = registry.state_init(cfg, self.n_lanes)
+            if mesh is not None:
+                self._state = jax.device_put(
+                    self._state, NamedSharding(mesh, PartitionSpec()))
+            self._state_reset = _state_reset_fn()
         if page_table is not None:
             self.pages: PageTable | None = page_table
-        elif page_size:
+        elif page_size and not stateful:
             self.pages = PageTable(
                 page_size,
                 capacity_pages=(page_capacity if page_capacity is not None
@@ -725,6 +759,12 @@ class ContinuousBatchingEngine:
         self._keys = self._keys.at[i].set(jnp.asarray(seed_key(sp.seed)))
         if req.sampling is not None:
             self.sampled_requests += 1
+        if self._state is not None:
+            # a new (or replayed) request's recurrence starts from zero
+            with tracing.span("engine.state_reset", self._trace):
+                self._state = self._state_reset(self._state, i)
+            if self._trace is not None:
+                self._trace.state_resets += 1
         slot = _Slot(request=req, seq=rec.arrival_seq)
         if match is not None:
             # shared prefix admitted pre-consumed. Paged backend: pure
@@ -974,9 +1014,9 @@ class ContinuousBatchingEngine:
             arena = self._arena
             tables, lengths = tables
             if chunk == 1 or int(counts.max()) <= 1:
-                nxt, arena.k, arena.v, self._keys = self._pstep(
-                    self.params, arena.k, arena.v, tables, lengths,
-                    jnp.asarray(toks[:, 0]), fb, prev,
+                nxt, arena.k, arena.v, self._state, self._keys = self._pstep(
+                    self.params, arena.k, arena.v, self._state, tables,
+                    lengths, jnp.asarray(toks[:, 0]), fb, prev,
                     jnp.asarray(counts > 0), em, self._keys, temp, tk, tp)
             else:
                 nxt, arena.k, arena.v, self._keys = self._pchunk(

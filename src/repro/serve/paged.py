@@ -97,14 +97,15 @@ def place_params(cfg: ModelConfig, params, mesh: Mesh,
 def pool_signature(cfg: ModelConfig) -> tuple:
     """Cache-shape signature of a config: configs with equal signatures can
     share one device arena (their KV pages are layout-compatible)."""
-    return (cfg.n_layers, cfg.n_kv_heads, cfg.resolved_head_dim)
+    return (registry.kv_layers(cfg), cfg.n_kv_heads, cfg.resolved_head_dim)
 
 
 @dataclasses.dataclass
 class PoolArena:
     """Device storage for one cache signature: a (k, v) pair shaped
-    ``(L·(n_pages + 1), page_size, Kh·Dh)`` — one flat page space in which
-    layer ``l``'s page ``p`` is row ``l·(n_pages + 1) + p``; each layer's
+    ``(L·(n_pages + 1), page_size, Kh·Dh)`` over the ``L`` layers that
+    hold KV — one flat page space in which layer ``l``'s page ``p`` is
+    row ``l·(n_pages + 1) + p``; each layer's
     extra row is its null page
     (:func:`repro.models.transformer.paged_pool_init`). Page ids stay in
     ``[0, n_pages]``; the step offsets them per layer. Engines mutate
@@ -297,28 +298,33 @@ def _decode_call(cfg: ModelConfig, window: int | None,
     tables / lengths / tokens ride replicated, and the one collective is
     the head all-gather inside the transformer (``tp_axis``). Outputs:
     logits replicated (every device computes the identical post-gather
-    tail), pools sharded as they came in.
+    tail), pools sharded as they came in. ``state`` is the per-slot state
+    arena of a config that ``registry.holds_state`` (None for the rest),
+    carried through and returned as the fourth output.
     """
+    def call(params, pool_k, pool_v, state, tables, lengths, tok, mask,
+             tp=None):
+        out = registry.decode_step_paged(
+            params, cfg, pool_k, pool_v, tables, lengths, tok,
+            append_mask=mask, window=window, tp_axis=tp, state=state)
+        return out if registry.holds_state(cfg) else (*out, None)
+
     if mesh is None:
-        def call(params, pool_k, pool_v, tables, lengths, tok, mask):
-            return registry.decode_step_paged(
-                params, cfg, pool_k, pool_v, tables, lengths, tok,
-                append_mask=mask, window=window)
         return call
 
     pool_spec = R.serve_pool_spec(tp_axis)
     param_specs = R.serve_param_specs(cfg, tp_axis)
     rep = PartitionSpec()
 
-    def local(params, pool_k, pool_v, tables, lengths, tok, mask):
-        return registry.decode_step_paged(
-            params, cfg, pool_k, pool_v, tables, lengths, tok,
-            append_mask=mask, window=window, tp_axis=tp_axis)
+    def local(params, pool_k, pool_v, state, tables, lengths, tok, mask):
+        return call(params, pool_k, pool_v, state, tables, lengths, tok,
+                    mask, tp_axis)
 
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(param_specs, pool_spec, pool_spec, rep, rep, rep, rep),
-        out_specs=(rep, pool_spec, pool_spec),
+        in_specs=(param_specs, pool_spec, pool_spec, rep, rep, rep, rep,
+                  rep),
+        out_specs=(rep, pool_spec, pool_spec, rep),
         check_vma=False)
 
 
@@ -326,9 +332,11 @@ def paged_step_fn(cfg: ModelConfig, window: int | None = None,
                   mesh: Mesh | None = None, tp_axis: str = "model"):
     """Jitted single-token paged decode over every lane.
 
-    Signature: ``(params, pool_k, pool_v, tables, lengths, toks, feedback,
-    prev, mask, emit, keys, temp, top_k, top_p) -> (next_tokens, pool_k',
-    pool_v', keys')`` where ``toks`` (B,) are host-chosen tokens,
+    Signature: ``(params, pool_k, pool_v, state, tables, lengths, toks,
+    feedback, prev, mask, emit, keys, temp, top_k, top_p) -> (next_tokens,
+    pool_k', pool_v', state', keys')`` where ``state`` is the per-slot
+    state arena of a config that ``registry.holds_state`` (None
+    otherwise), ``toks`` (B,) are host-chosen tokens,
     ``feedback`` (B,) selects the previous step's on-device next token
     ``prev`` instead (async double-buffering), and ``mask`` (B,) gates the
     KV append (False = idle/stalled lane riding the batch). ``keys`` is the
@@ -340,7 +348,7 @@ def paged_step_fn(cfg: ModelConfig, window: int | None = None,
     ``window`` (sliding-window configs) switches the block tables to ring
     semantics — pass the engine's *clamped* window (``min(cfg.sliding_
     window, device cache length)``) so the decode stays bit-identical to
-    the lane ring cache. Pools and keys are donated.
+    the lane ring cache. Pools, state and keys are donated.
 
     ``mesh`` switches the decode to tensor parallelism over ``tp_axis``
     (:func:`_decode_call`): the same jitted step, with the transformer
@@ -352,18 +360,18 @@ def paged_step_fn(cfg: ModelConfig, window: int | None = None,
     if key not in _PAGED_FNS:
         decode = _decode_call(cfg, window, mesh, tp_axis)
 
-        def step(params, pool_k, pool_v, tables, lengths, toks, feedback,
-                 prev, mask, emit, keys, temp, top_k, top_p):
+        def step(params, pool_k, pool_v, state, tables, lengths, toks,
+                 feedback, prev, mask, emit, keys, temp, top_k, top_p):
             tok = jnp.where(feedback, prev, toks)
-            logits, pool_k, pool_v = decode(
-                params, pool_k, pool_v, tables, lengths, tok, mask)
+            logits, pool_k, pool_v, state = decode(
+                params, pool_k, pool_v, state, tables, lengths, tok, mask)
             with jax.named_scope("sample"):
                 carry, use = split_keys(keys)
                 nxt = jax.vmap(sample)(logits, use, temp, top_k, top_p)
                 keys = jnp.where(emit[:, None], carry, keys)
-            return nxt, pool_k, pool_v, keys
+            return nxt, pool_k, pool_v, state, keys
 
-        _PAGED_FNS[key] = jax.jit(step, donate_argnums=(1, 2, 10))
+        _PAGED_FNS[key] = jax.jit(step, donate_argnums=(1, 2, 3, 11))
     return _PAGED_FNS[key]
 
 
@@ -383,6 +391,7 @@ def paged_chunk_fn(cfg: ModelConfig, chunk: int, window: int | None = None,
     whose prefill completes this launch, and decode lanes). ``window``
     and ``mesh``/``tp_axis`` as in :func:`paged_step_fn` (the sharded
     decode runs per scan iteration; the scan carry is the sharded pool).
+    A config that ``registry.holds_state`` has no chunked step.
     """
     key = ("chunk", cfg, chunk, window, mesh,
            tp_axis if mesh is not None else None)
@@ -397,8 +406,8 @@ def paged_chunk_fn(cfg: ModelConfig, chunk: int, window: int | None = None,
                 pool_k, pool_v = carry
                 j, tok_j = xs
                 tok = jnp.where((j == 0) & feedback, prev, tok_j)
-                logits, pool_k, pool_v = decode(
-                    params, pool_k, pool_v, tables, lengths + j, tok,
+                logits, pool_k, pool_v, _ = decode(
+                    params, pool_k, pool_v, None, tables, lengths + j, tok,
                     j < counts)
                 with jax.named_scope("sample"):
                     nxt = jax.vmap(sample)(logits, use, temp, top_k, top_p)

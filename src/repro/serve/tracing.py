@@ -4,22 +4,25 @@ Every :meth:`ContinuousBatchingEngine.step` that does work leaves two
 traces of itself:
 
 * **Spans** in the profiler's host plane. ``engine.step`` covers the
-  call; inside it one span per phase, none nested in another:
+  call; inside it one span per phase, none nested in another but
+  ``engine.state_reset``, which runs inside ``engine.admit``:
 
-  ============== ==========================================================
-  span           what the engine does in it
-  ============== ==========================================================
-  engine.meter   the energy meter's clock tick and its per-step charge
-  engine.admit   admission: page-table acquire, journal open, lane seed
-  engine.batch   the batch: tokens, counts, emit flags, pages to write
-  engine.tables  block tables and lengths, and their host→device copies
-  engine.launch  argument conversion and the jitted call, until it returns
-                 (the step is enqueued, not finished)
-  engine.account bookkeeping after the launch: fed, publish, evict
-  engine.wait    the wait for the step's tokens (``jax.device_get``)
-  engine.retire  token delivery, journal and completions
-  engine.recover quarantine recovery (requeue of faulted requests)
-  ============== ==========================================================
+  ================== =======================================================
+  span               what the engine does in it
+  ================== =======================================================
+  engine.meter       the energy meter's clock tick and its per-step charge
+  engine.admit       admission: page-table acquire, journal open, lane seed
+  engine.state_reset (models with recurrent state) the launch that zeroes
+                     an admitted slot's rows of the state arena
+  engine.batch       the batch: tokens, counts, emit flags, pages to write
+  engine.tables      block tables and lengths, and their host→device copies
+  engine.launch      argument conversion and the jitted call, until it
+                     returns (the step is enqueued, not finished)
+  engine.account     bookkeeping after the launch: fed, publish, evict
+  engine.wait        the wait for the step's tokens (``jax.device_get``)
+  engine.retire      token delivery, journal and completions
+  engine.recover     quarantine recovery (requeue of faulted requests)
+  ================== =======================================================
 
   The spans are ``jax.profiler.TraceAnnotation``\\ s, so a profiler
   trace puts each device op and each idle gap beside the host work that
@@ -59,8 +62,9 @@ class StepTrace:
     a step that did not launch or did not wait. ``phases`` maps a span
     name to its seconds in this step. ``prompt_fed`` and ``decode_fed``
     are the prompt and decode tokens fed to the device, ``emitted`` the
-    tokens the step generates, ``admitted`` the requests it admitted and
-    ``queued`` the queue depth after admission."""
+    tokens the step generates, ``admitted`` the requests it admitted,
+    ``queued`` the queue depth after admission and ``state_resets`` the
+    slots whose recurrent state it zeroed."""
 
     engine: str
     start: float
@@ -73,6 +77,7 @@ class StepTrace:
     emitted: int = 0
     admitted: int = 0
     queued: int = 0
+    state_resets: int = 0
 
 
 class span:

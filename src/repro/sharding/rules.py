@@ -175,6 +175,8 @@ def validate_serve_tp(cfg, tp: int) -> None:
 
     * a transformer-family config with paged KV decode (MoE and the
       SSM/hybrid lane-fallback families have no head axis to shard);
+    * at tp > 1, no per-slot recurrent state (a Mamba-2 hybrid's state
+      arena has no head-sharded layout);
     * ``n_kv_heads % tp == 0`` — the pool arenas shard over the KV-head
       axis, so every device must hold whole KV heads (this also implies
       ``n_heads % tp == 0``: query heads are ``groups × n_kv_heads``).
@@ -191,6 +193,11 @@ def validate_serve_tp(cfg, tp: int) -> None:
             "and SSM/hybrid lane caches have no KV-head axis to shard)")
     if tp == 1:
         return
+    if registry.holds_state(cfg):
+        raise ValueError(
+            f"{cfg.name} ({cfg.family}) cannot serve tensor-parallel at tp "
+            f"{tp}: its per-slot recurrent state arena has no head-sharded "
+            "layout")
     if cfg.n_kv_heads % tp:
         detail = ("MQA has a single shared KV head" if cfg.n_kv_heads == 1
                   else f"{cfg.n_kv_heads} KV heads")

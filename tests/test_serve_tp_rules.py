@@ -14,7 +14,8 @@ agree with the registry about which configs it can shard and how:
   counterpart of ``spec_for``'s silent divisibility fallback, which would
   quietly replicate arenas the caller asked to split;
 * MoE and the SSM/hybrid lane-fallback families are rejected at any tp
-  (no paged KV, no head axis to shard), again naming the reason.
+  (no paged KV, no head axis to shard), again naming the reason; the
+  Mamba-2 hybrid, paged with a per-slot state arena, at any tp > 1.
 """
 
 import dataclasses
@@ -28,8 +29,10 @@ from repro.sharding import axes as lx
 from repro.sharding import rules as R
 from repro.sharding.params import Axes, axes_tree, is_axes, map_decls
 
+# head-sharded serving: paged configs without per-slot recurrent state
 PAGED = [a for a in configs.names()
-         if registry.supports_paged(configs.smoke(a))]
+         if registry.supports_paged(configs.smoke(a))
+         and not registry.holds_state(configs.smoke(a))]
 UNPAGED = [a for a in configs.names()
            if not registry.supports_paged(configs.smoke(a))]
 
@@ -110,6 +113,18 @@ def test_lane_fallback_families_rejected(arch):
     cfg = configs.smoke(arch)
     for tp in (1, 2):
         with pytest.raises(ValueError, match="cannot serve tensor-parallel"):
+            R.validate_serve_tp(cfg, tp)
+
+
+def test_recurrent_state_cannot_shard_beyond_one():
+    """A Mamba-2 hybrid serves on one device (tp=1) and is refused at any
+    tp > 1, naming its state arena, though its KV heads would divide."""
+    cfg = configs.smoke("granite_4_0_h_micro")
+    assert registry.supports_paged(cfg) and registry.holds_state(cfg)
+    R.validate_serve_tp(cfg, 1)
+    for tp in (2, cfg.n_kv_heads):
+        assert cfg.n_kv_heads % tp == 0
+        with pytest.raises(ValueError, match="recurrent state arena"):
             R.validate_serve_tp(cfg, tp)
 
 

@@ -21,6 +21,14 @@ Two kinds of case, one test:
   ``dynamic-slice`` or ``dynamic-update-slice`` shaped like the arena or
   one layer's pool, and temporaries under 1% of the arena's bytes.
 
+A third case compiles granite-4.0-h-micro's paged step at one period of
+its layer pattern (9 Mamba-2 layers and 1 attention layer) and 64 slots,
+with the KV arena (the attention layer's pages only) and the state arena
+donated: the ``ssm_decode`` kernel updates each layer's state rows in
+place, so no copy, slice or update of the state arena (or of one layer's
+state) appears, no move of the KV arena, and the temporaries stay under
+1% of the two arenas.
+
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every test worker imports
 every test file.
@@ -39,7 +47,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceShardin
 from repro import configs
 from repro.kernels import backend
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
-from repro.models import registry
+from repro.models import hybrid, registry
 from repro.serve import paged
 from repro.sharding import params as P
 from repro.sharding import rules as R
@@ -116,7 +124,7 @@ def _compile_step(topo, monkeypatch, arch, slots, tp):
         rep = NamedSharding(mesh, PartitionSpec())
         call = paged._decode_call(cfg, None, mesh, "model")
         fn = lambda p, k, v, t, n, x: call(  # noqa: E731
-            p, k, v, t, n, x, jnp.ones(x.shape, bool))
+            p, k, v, None, t, n, x, jnp.ones(x.shape, bool))
     pool = jax.ShapeDtypeStruct(arena, jnp.bfloat16, sharding=pool_sh)
     args = (params, pool, pool,
             jax.ShapeDtypeStruct((slots, ENTRIES), jnp.int32, sharding=rep),
@@ -165,3 +173,49 @@ def test_paged_attention_kernel_compiles_for_v5e(topo, monkeypatch, what,
         assert _arena_moves(text, rows) == []
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < 0.01 * arena_bytes, (temp, arena_bytes)
+
+
+def test_hybrid_paged_step_keeps_both_arenas_in_place(topo, monkeypatch):
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    base = configs.get("granite_4_0_h_micro")
+    period = len(hybrid.period(base))
+    cfg = dataclasses.replace(base, n_layers=period,
+                              layer_types=base.layer_types[:period])
+    slots = 64
+    rows = slots * ENTRIES + 1
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = P.map_decls(lambda d: spec(d.shape, jnp.bfloat16),
+                         registry.decls(cfg))
+    pool = spec((hybrid.n_attention(cfg) * rows, PAGE,
+                 cfg.n_kv_heads * cfg.resolved_head_dim), jnp.bfloat16)
+    state = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                         jax.eval_shape(lambda: registry.state_init(cfg, slots)))
+    ints = spec((slots,), jnp.int32)
+
+    def step(p, k, v, st, t, n, x):
+        return registry.decode_step_paged(p, cfg, k, v, t, n, x, state=st)
+
+    compiled = jax.jit(step, donate_argnums=(1, 2, 3)).lower(
+        params, pool, pool, state, spec((slots, ENTRIES), jnp.int32), ints,
+        ints).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%ssm_decode[.\d]* = .*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == hybrid.n_mamba(cfg)
+    for scope in ("ssm.in_proj", "ssm.conv", "ssm.kernel", "ssm.out"):
+        assert f"/{scope}/" in text, scope
+    assert re.search(r"%paged_attention[.\d]* = ", text)
+    assert _arena_moves(text, rows) == []
+    layer = ",".join(map(str, state.state.shape[1:]))
+    moves = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r"= f32\[(\d+,)?%s\]\S* (%s)\(" % (
+                 layer, "|".join(_MOVES)), line)]
+    assert moves == []
+    arenas = 2 * np.prod(pool.shape) * 2 + sum(
+        np.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(state))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.01 * arenas, (temp, arenas)
